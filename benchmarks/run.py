@@ -35,6 +35,9 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="substring filter on table module names")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     print("name,us_per_call,derived")
     failures = []
     for mod_name in TABLES:

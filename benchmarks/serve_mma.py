@@ -2,8 +2,8 @@
 levels (DESIGN.md §13.5).
 
 Dense serve levels have two kernel formulations: the packed layout's
-fused scalar-prefetch gather (``kernels/pull_scatter_ms_packed.py``, one
-grid pass walking every VSS row) and the PR 6 blocked bit-matrix product
+fused selective-OR pull+scatter (``kernels/pull_scatter_ms_packed.py``,
+one grid pass walking every VSS block) and the blocked bit-matrix product
 (``kernels/pull_mma_ms_packed.py``), which unpacks the VSS bit-tiles to
 int8 planes once at tile prep and turns each dense sweep into MXU-shaped
 ``(block, tau, sigma) x (block, sigma, kappa)`` batched matmuls.  On CPU

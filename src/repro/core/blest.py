@@ -22,6 +22,7 @@ Update mechanics:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple
 
 import jax
@@ -35,6 +36,11 @@ UNREACHED = np.iinfo(np.int32).max
 VSS_PAD = 8  # N_v padded to a multiple of this (and >= 1 extra padding row)
 
 
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["masks", "masks_packed", "row_ids", "v2r", "real_ptrs"],
+    meta_fields=["n", "n_pad", "n_ext", "num_sets", "num_sets_ext",
+                 "num_vss", "num_vss_pad", "sigma", "tau"])
 @dataclasses.dataclass(frozen=True)
 class BvssDevice:
     """BVSS moved to device, padded for tiling.
@@ -43,6 +49,11 @@ class BvssDevice:
     inactive slice set) and ``row_ids == n_pad`` (an extra, ignored vertex
     slot).  V/level arrays are sized ``n_ext = n_pad + sigma`` so sentinel
     scatters land in-bounds but outside the reported range.
+
+    A pytree (the sizes are static): jitted code takes it as an argument.
+    A jitted closure over it would embed every array in the program as a
+    constant — at scale 20 that is ~100 MB per program, minutes of
+    compilation, and a device copy per executable.
     """
 
     n: int
@@ -142,6 +153,8 @@ def _scatter_and_sweep(bd: BvssDevice, state: BfsState, marks, row_ids, *,
     return BfsState(v_new, level_new, f_words, state.ell + 1)
 
 
+@functools.partial(jax.jit, static_argnames=("lazy", "use_pallas", "packed",
+                                             "max_levels"))
 def bfs_fused(
     bd: BvssDevice,
     src,
@@ -170,26 +183,19 @@ def bfs_fused(
     return final.level[: bd.n]
 
 
-# jit once per (bd identity, flags); bd is static through closure
 @dataclasses.dataclass
 class FusedBfs:
-    """jit-compiled fused BFS bound to one graph (source is a runtime arg)."""
+    """Fused BFS bound to one graph (source is a runtime arg); compiles once
+    per graph shape and flags."""
 
     bd: BvssDevice
     lazy: bool = True
     use_pallas: bool = True
     packed: bool = True
 
-    def __post_init__(self):
-        bd = self.bd
-        self._fn = jax.jit(
-            lambda src: bfs_fused(bd, src, lazy=self.lazy,
-                                  use_pallas=self.use_pallas,
-                                  packed=self.packed)
-        )
-
     def __call__(self, src) -> jax.Array:
-        return self._fn(jnp.asarray(src, jnp.int32))
+        return bfs_fused(self.bd, jnp.asarray(src, jnp.int32), lazy=self.lazy,
+                         use_pallas=self.use_pallas, packed=self.packed)
 
 
 # --------------------------------------------------------------------------
@@ -248,12 +254,13 @@ class BucketedBfs:
         bd = self.bd
         self.trace: list[dict] = []
 
-        @jax.jit
-        def dense_level(state: BfsState) -> BfsState:
+        # the graph is an argument, not a closure (see BvssDevice)
+        def dense_level(bd: BvssDevice, state: BfsState) -> BfsState:
             return _level_dense(bd, state, lazy=self.lazy,
                                 use_pallas=self.use_pallas, packed=self.packed)
 
-        def queued_level(state: BfsState, qids: jax.Array) -> BfsState:
+        def queued_level(bd: BvssDevice, state: BfsState,
+                         qids: jax.Array) -> BfsState:
             masks = (bd.masks_packed if self.packed else bd.masks)[qids]
             rows = bd.row_ids[qids]
             alphas = state.f_words[bd.v2r[qids]]
@@ -263,8 +270,8 @@ class BucketedBfs:
             return _scatter_and_sweep(bd, state, marks, rows, lazy=self.lazy,
                                       use_pallas=self.use_pallas)
 
-        self._dense_level = dense_level
-        self._queued_level = jax.jit(queued_level)
+        self._dense_level = functools.partial(jax.jit(dense_level), bd)
+        self._queued_level = functools.partial(jax.jit(queued_level), bd)
         # host-side copies for queue expansion
         self._real_ptrs = np.asarray(bd.real_ptrs)
         self._pad_vss = bd.num_vss  # a guaranteed padding VSS id

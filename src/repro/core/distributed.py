@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import msbfs
 from repro.core.bvss import Bvss
@@ -67,9 +66,9 @@ def closeness_source_parallel(
     padded[: len(sources)] = sources
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(source_axes),), out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def run(srcs_shard):
         far = jnp.zeros(bd.n_ext, jnp.int32)
@@ -133,10 +132,10 @@ def bfs_graph_parallel(
     max_lv = bd.n_ext if max_levels is None else max_levels
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def run(masks_l, rows_l, v2r_l, src_arr):
         state = init_state(bd, src_arr[0])
@@ -272,10 +271,10 @@ def bfs_row_parallel(
     n_local = rs.rows_per + sigma  # + sentinel slot range
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P()),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
     def run(masks_s, rows_s, v2r_s, src_arr):
         masks_l = masks_s[0]

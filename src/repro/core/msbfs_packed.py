@@ -34,7 +34,7 @@ from repro.kernels.scatter_or import scatter_or
 class PackedMsBfs:
     bd: BvssDevice
     interpret: bool | None = None
-    # 'gather' — scalar-prefetch selective-OR pull (kernels/pull_ms_packed);
+    # 'gather' — blocked selective-OR pull (kernels/pull_ms_packed);
     # 'mma'    — blocked binary-MMA pull (kernels/pull_mma_ms_packed,
     #            DESIGN.md §13): same marks, computed as bit-matrix products
     kernel: str = "gather"

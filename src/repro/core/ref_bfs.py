@@ -42,13 +42,11 @@ def bfs_levels(g: Graph, src: int) -> np.ndarray:
 
 
 def _gather_ranges(cols, starts, ends, total):
-    out = np.empty(total, dtype=cols.dtype)
-    off = 0
-    for s, e in zip(starts, ends):
-        c = e - s
-        out[off : off + c] = cols[s:e]
-        off += c
-    return out
+    """Concatenation of ``cols[s:e]`` over the ranges, without a Python
+    loop: each output slot reads its range start plus its offset."""
+    counts = ends - starts
+    shift = np.repeat(starts - (np.cumsum(counts) - counts), counts)
+    return cols[np.arange(total) + shift]
 
 
 def bfs_levels_direction_optimizing(

@@ -109,6 +109,19 @@ def triangles_per_vertex_ref(g: Graph) -> np.ndarray:
     return (a2 * a).sum(axis=1) // 2
 
 
+def triangles_of_vertex_ref(sym_csr, v: int) -> int:
+    """Oracle for one vertex: row ``v`` of :func:`triangles_per_vertex_ref`,
+    ``sum_{u in N(v)} |N(v) & N(u)| // 2``, from the symmetrized CSR
+    (``g.symmetrized().csr``: rows deduplicated, self-loops kept).  Host
+    memory O(n + m), so it checks graphs whose dense n x n matrix does not
+    fit."""
+    ptrs, cols = sym_csr
+    nv = cols[ptrs[v]:ptrs[v + 1]]
+    total = sum(np.intersect1d(nv, cols[ptrs[u]:ptrs[u + 1]],
+                               assume_unique=True).size for u in nv)
+    return total // 2
+
+
 class TpvState:
     """Per-graph device state for on-demand single-vertex triangle queries
     (the serve engine's ``tpv`` graph state, DESIGN.md §15.2): the packed

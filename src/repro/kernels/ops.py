@@ -77,9 +77,11 @@ def frontier_sweep(v_curr, v_next, level, ell, *, sigma: int = 8,
         return kref.frontier_sweep_ref(v_curr, v_next, level, ell, sigma=sigma)
     interpret = _interpret_default() if interpret is None else interpret
     n_pad = v_curr.shape[0]
-    if block_n is None:
-        block_n = min(_sweep.DEFAULT_BLK_N, n_pad)
-    # n_pad is a multiple of sigma by construction; make it a block multiple
+    # the kernel works on rows of 128*sigma vertices: round the block to
+    # whole rows, and to one block when the array is smaller
+    row = _sweep.LANES * sigma
+    block_n = _sweep.DEFAULT_BLK_N if block_n is None else block_n
+    block_n = min(-(-block_n // row), -(-n_pad // row)) * row
     rem = (-n_pad) % block_n
     if rem:
         v_curr = jnp.pad(v_curr, (0, rem))

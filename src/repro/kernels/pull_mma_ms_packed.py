@@ -28,21 +28,21 @@ Three entry points, each with a bit-identical jnp reference twin (the PR 4
 pattern — the twin is the CPU path and the oracle):
 
 * :func:`pull_mma_ms_packed` — the blocked Pallas kernel: the grid walks
-  ``n_q // block`` steps, each feeding the MXU one batched
-  ``(block, tau, sigma) x (block, sigma, kappa)`` int8 ``dot_general`` and
-  packing the sign of the counts back to ``(block, tau, kw)`` uint32 marks.
-  The frontier tiles are pre-gathered by XLA (``f_packed[v2r]``) so the
-  grid can block over VSS tiles — the one deliberate departure from the
-  scalar-prefetch pulls, which trade blocking for gather-freedom.
+  ``n_q // block`` steps; per VSS tile it feeds the MXU one int8
+  ``(kappa, sigma) x (sigma, tau)`` product (:func:`mma_marks`, the
+  frontier planes transposed so the tau slots land on the lanes) and packs
+  the sign of the counts back to uint32 marks.  The frontier tiles are
+  pre-gathered by XLA (``f_packed[v2r]``) so the grid can block over VSS
+  tiles.
 * :func:`pull_scatter_mma_ms_packed` — the fused scatter variant
-  (DESIGN.md §11.2 applied to the MMA pull): phase 2 computes each mark
-  row as a ``(1, sigma) x (sigma, kappa)`` product and ORs it straight
-  into the live visited words, so the marks array never exists.  Its jnp
-  twin exploits the count formulation: integer counts are scatter-**add**
-  safe (OR is not XLA-native), so one ``at[].add`` pass replaces the
-  32-bit-plane scatter-max ladder of ``scatter_or_ref`` — the popcount
-  path, and the reason the MMA layout beats the fused gather kernel on
-  dense levels off-TPU (benchmarks/serve_mma.py).
+  (DESIGN.md §11.2 applied to the MMA pull): each block's MMA marks go
+  straight into the VMEM-resident visited words through the
+  :mod:`kernels.scatter_or` machinery, so the marks array never reaches
+  HBM.  Its jnp twin exploits the count formulation: integer counts are
+  scatter-**add** safe (OR is not XLA-native), so one ``at[].add`` pass
+  replaces the 32-bit-plane scatter-max ladder of ``scatter_or_ref`` —
+  the popcount path, and the reason the MMA layout beats the fused gather
+  kernel on dense levels off-TPU (benchmarks/serve_mma.py).
 * :func:`pull_mma_byteplane_ref` — the AND-OR/popcount fallback for the
   byteplane substrate: same counts-matmul over uint8 bit-planes,
   bit-identical to ``kernels.ref.pull_ms_ref``.
@@ -62,8 +62,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-MMA_VSS_BLOCK = 8  # VSS tiles per grid step (batched MXU dot)
+from repro.kernels.pull_ms_packed import frontier_tiles
+from repro.kernels.scatter_or import (copy_in_first_step, from_lane_rows,
+                                      pad_blocks, resident_scatter_call,
+                                      scatter_block, to_lane_rows)
+
+MMA_VSS_BLOCK = 8  # VSS tiles per grid step (one MXU dot each)
 
 
 # ---------------------------------------------------------------------------
@@ -79,11 +85,15 @@ def unpack_mask_planes(masks: np.ndarray, sigma: int) -> np.ndarray:
         np.int8)
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["a_planes", "v2r", "rows", "nz_planes"],
+                   meta_fields=["block"])
 @dataclasses.dataclass(frozen=True)
 class MmaTiles:
     """Graph-static MMA operands (DESIGN.md §13.1), device-resident and
     counted against the :class:`~repro.serve.bfs_engine.GraphCache` byte
-    budget like every other per-graph substrate array.
+    budget like every other per-graph substrate array.  A pytree, so jitted
+    code takes the tiles as an argument (never as embedded constants).
 
     ``a_planes``/``v2r``/``rows`` serve the packed-word kernels; the VSS
     dimension is padded to a multiple of ``block`` with masked tiles (zero
@@ -160,17 +170,43 @@ def _pack_bits(bits):
     return (bits.astype(jnp.uint32) << shifts).sum(axis=-1).astype(jnp.uint32)
 
 
-def _pull_mma_kernel(a_ref, ft_ref, out_ref, *, kw):
-    a = a_ref[...]                       # (B, tau, sigma) int8
-    ft = ft_ref[...]                     # (B, sigma, kw) uint32
-    planes = _unpack_words(ft, kw)       # (B, sigma, kappa) int8
-    # the binary MMA: one batched int8 product per grid step; every element
-    # of the (tau, kappa) output tile is a needed neighbor check
+def mma_marks(a, ft_row, *, sigma: int, kw: int):
+    """Binary MMA pull of one VSS on the MXU: ``a`` (tau, sigma) int8 mask
+    planes, ``ft_row`` (1, kw*sigma) int32 frontier words (``w*sigma+b``)
+    -> kw arrays (1, tau) int32 of mark word w, slots on the lanes.
+
+    The frontier planes are built transposed, ``x[l, b]`` = bit ``l % 32``
+    of word ``(b, l // 32)``, so the one int8 product
+    ``x (kappa, sigma) . a^T`` yields the (kappa, tau) count tile with the
+    tau slots on the lanes; packing its signs is then a sublane sum of
+    distinct bits (exact in int32)."""
+    kappa = 32 * kw
+    lane = jax.lax.broadcasted_iota(jnp.int32, (kappa, sigma), 0)
+    x = jnp.zeros((kappa, sigma), jnp.int32)
+    for w in range(kw):
+        word = ft_row[:, w * sigma:(w + 1) * sigma]          # (1, sigma)
+        x = jnp.where(lane // 32 == w, (word >> (lane % 32)) & 1, x)
     counts = jax.lax.dot_general(
-        a, planes, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.int32)  # (B, tau, kappa)
-    bits = (counts > 0).reshape(*counts.shape[:-1], kw, 32)
-    out_ref[...] = _pack_bits(bits)
+        x.astype(jnp.int8), a, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.int32)                     # (kappa, tau)
+    shift = jax.lax.broadcasted_iota(jnp.int32, (32, 1), 0)
+    return [jnp.sum((counts[w * 32:(w + 1) * 32] > 0).astype(jnp.int32)
+                    << shift, axis=0, keepdims=True) for w in range(kw)]
+
+
+def _mma_block_marks(a_ref, ft_ref, marks_ref, *, sigma, kw):
+    """Fill ``marks_ref`` (block, kw*tau) with one MMA pull per VSS."""
+    @pl.loop(0, a_ref.shape[0])
+    def _(q):
+        words = mma_marks(a_ref[q], ft_ref[pl.ds(q, 1), :], sigma=sigma,
+                          kw=kw)
+        marks_ref[pl.ds(q, 1), :] = jnp.concatenate(words, axis=1)
+
+
+def _pull_mma_kernel(a_ref, ft_ref, out_ref, *, sigma, kw):
+    # the binary MMA: one int8 product per VSS tile; every element of the
+    # (kappa, tau) count tile is a needed neighbor check
+    _mma_block_marks(a_ref, ft_ref, out_ref, sigma=sigma, kw=kw)
 
 
 @functools.partial(jax.jit, static_argnames=("sigma", "block", "interpret"))
@@ -195,19 +231,20 @@ def pull_mma_ms_packed(
             f"% block {block} != 0 — run prep_mma_tiles (pad-and-mask), the "
             f"kernel does not truncate ragged last tiles")
     # XLA pre-gathers the per-VSS frontier tiles so the grid can block over
-    # VSS tiles (the scalar-prefetch pulls cannot batch the MXU this way)
-    f_tiles = f_packed[v2r]
-    return pl.pallas_call(
-        functools.partial(_pull_mma_kernel, kw=kw),
+    # VSS tiles
+    out = pl.pallas_call(
+        functools.partial(_pull_mma_kernel, sigma=sigma, kw=kw),
         grid=(n_q // block,),
         in_specs=[
             pl.BlockSpec((block, tau, sigma), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block, sigma, kw), lambda i: (i, 0, 0)),
+            pl.BlockSpec((block, kw * sigma), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((block, tau, kw), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_q, tau, kw), jnp.uint32),
+        out_specs=pl.BlockSpec((block, kw * tau), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_q, kw * tau), jnp.int32),
         interpret=interpret,
-    )(a_planes, f_tiles)
+    )(a_planes, frontier_tiles(f_packed, v2r))
+    out = out.reshape(n_q, kw, tau).transpose(0, 2, 1)
+    return jax.lax.bitcast_convert_type(out, jnp.uint32)
 
 
 def pull_mma_ms_packed_ref(a_planes, f_tiles):
@@ -227,20 +264,12 @@ def pull_mma_ms_packed_ref(a_planes, f_tiles):
 # ---------------------------------------------------------------------------
 
 
-def _pull_scatter_mma_kernel(rows_ref, v2r_ref, dest_ref, a_ref, f_ref,
-                             out_ref, *, n_rows, kw):
-    del rows_ref, v2r_ref  # consumed by the index maps only
-    s = pl.program_id(0)
-    init_phase = s < n_rows
-    a = a_ref[...]                       # (1, sigma) int8 — this slot's row
-    f = f_ref[...][0]                    # (sigma, kw) uint32
-    planes = _unpack_words(f, kw)        # (sigma, kappa) int8
-    counts = jax.lax.dot_general(
-        a, planes, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)  # (1, kappa)
-    acc = _pack_bits((counts[0] > 0).reshape(kw, 32))  # (kw,) uint32
-    cur = out_ref[...]
-    out_ref[...] = jnp.where(init_phase, dest_ref[...], cur | acc[None])
+def _pull_scatter_mma_kernel(v_hbm, a_ref, ft_ref, rows_ref, out_ref,
+                             marks_v, marks_s, *, sigma, kw):
+    copy_in_first_step(v_hbm, out_ref)
+    _mma_block_marks(a_ref, ft_ref, marks_v, sigma=sigma, kw=kw)
+    pltpu.sync_copy(marks_v, marks_s)
+    scatter_block(out_ref, rows_ref, marks_s, kw=kw, width=a_ref.shape[1])
 
 
 @functools.partial(jax.jit, static_argnames=("sigma", "interpret"))
@@ -255,47 +284,28 @@ def pull_scatter_mma_ms_packed(
     interpret: bool = False,
 ) -> jax.Array:
     """Returns ``v`` with the MMA pull's marks OR-scattered in — the
-    §11.2 fused grid (init copy, then one slot per step) with the mark row
-    computed as a ``(1, sigma) x (sigma, kappa)`` product instead of the
-    selective-OR ladder.  Bit-identical to ``pull_scatter_ms_packed``."""
-    import jax.experimental.pallas.tpu as pltpu
-
-    n_rows, kw = v.shape
+    §11.2 fused grid (VMEM-resident visited words, one VSS block per
+    step) with the marks computed as binary products on the MXU instead
+    of the selective-OR ladder.  Bit-identical to
+    ``pull_scatter_ms_packed``."""
+    kw = v.shape[1]
     n_q, tau, sig = a_planes.shape
     assert sig == sigma
-    t = rows.shape[0]
-    assert t == n_q * tau
-    a_flat = a_planes.reshape(t, sigma)
-
-    def dest_index(s, rows_, v2r_):
-        return (jnp.where(s < n_rows, s, 0), 0)
-
-    def a_index(s, rows_, v2r_):
-        return (jnp.clip(s - n_rows, 0, t - 1), 0)
-
-    def f_index(s, rows_, v2r_):
-        return (v2r_[jnp.clip(s - n_rows, 0, t - 1) // tau], 0, 0)
-
-    def out_index(s, rows_, v2r_):
-        e = jnp.clip(s - n_rows, 0, t - 1)
-        return (jnp.where(s < n_rows, s, rows_[e]), 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_rows + t,),
-        in_specs=[
-            pl.BlockSpec((1, kw), dest_index),
-            pl.BlockSpec((1, sigma), a_index),
-            pl.BlockSpec((1, sigma, kw), f_index),
-        ],
-        out_specs=pl.BlockSpec((1, kw), out_index),
-    )
-    return pl.pallas_call(
-        functools.partial(_pull_scatter_mma_kernel, n_rows=n_rows, kw=kw),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(v.shape, v.dtype),
-        interpret=interpret,
-    )(rows, v2r, v, a_flat, f_packed)
+    assert rows.shape[0] == n_q * tau
+    blk, (a_planes, ft, rows2) = pad_blocks(
+        n_q, a_planes, frontier_tiles(f_packed, v2r), rows.reshape(n_q, tau))
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    out = resident_scatter_call(
+        functools.partial(_pull_scatter_mma_kernel, sigma=sigma, kw=kw),
+        to_lane_rows(v), (a_planes, ft, rows2),
+        [pl.BlockSpec((blk, tau, sigma), lambda i: (i, 0, 0)),
+         pl.BlockSpec((blk, kw * sigma), lambda i: (i, 0)),
+         smem((blk, tau), lambda i: (i, 0))],
+        grid=a_planes.shape[0] // blk,
+        scratch_shapes=[pltpu.VMEM((blk, kw * tau), jnp.int32),
+                        pltpu.SMEM((blk, kw * tau), jnp.int32)],
+        interpret=interpret)
+    return from_lane_rows(out, v.shape)
 
 
 def pull_scatter_mma_ms_packed_ref(v, a_planes, f_packed, v2r, rows):

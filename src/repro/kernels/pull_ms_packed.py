@@ -9,6 +9,11 @@ i.e. at most sigma selective ORs of kappa/32-word rows — no unpacking, no
 matmul, 1/8 the frontier bytes of the byte-plane path.  Paired with
 kernels/scatter_or.py this keeps the whole MS-BFS state packed end-to-end
 (§Perf cell-1 iteration 4).
+
+The kernel blocks over VSSs: XLA gathers each VSS's parent frontier tile
+(:func:`frontier_tiles`), and one grid step computes a ``(block, tau)``
+mask tile against them with the slots on the lanes, writing the marks
+lane-dense as ``(block, kw*tau)``; the wrapper restores ``(N_q, tau, kw)``.
 """
 from __future__ import annotations
 
@@ -17,20 +22,37 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.scatter_or import pad_blocks
 
 
-def _pull_ms_packed_kernel(v2r_ref, masks_ref, f_ref, out_ref, *, sigma):
-    del v2r_ref
-    mask = masks_ref[...][0]      # (tau,) uint8
-    f = f_ref[...][0]             # (sigma, kw) uint32
-    kw = f.shape[1]
-    acc = jnp.zeros((mask.shape[0], kw), jnp.uint32)
-    for b in range(sigma):
-        sel = ((mask >> b) & 1).astype(jnp.uint32)[:, None]  # (tau, 1)
-        # sel in {0,1}: 0-sel = all-ones / all-zeros word (multiply-free)
-        acc = acc | ((jnp.uint32(0) - sel) & f[b][None, :])
-    out_ref[...] = acc[None]
+def frontier_tiles(f_packed: jax.Array, v2r: jax.Array) -> jax.Array:
+    """(num_sets, sigma, kw) uint32 frontier words gathered per VSS ->
+    (N_q, kw*sigma) int32 with word ``w`` of bit-row ``b`` at ``w*sigma+b``."""
+    ft = jnp.swapaxes(f_packed[v2r], 1, 2)
+    return jax.lax.bitcast_convert_type(ft, jnp.int32).reshape(v2r.shape[0], -1)
+
+
+def packed_marks(m, ft, *, sigma: int, kw: int):
+    """Selective-OR pull of one block: ``m`` (B, tau) int32 masks, ``ft``
+    (B, kw*sigma) frontier tiles -> kw arrays (B, tau) int32 of mark word w."""
+    sels = [((m >> b) & 1) != 0 for b in range(sigma)]
+    out = []
+    for w in range(kw):
+        acc = jnp.zeros(m.shape, jnp.int32)
+        for b in range(sigma):
+            c = w * sigma + b
+            acc = acc | jnp.where(sels[b], ft[:, c:c + 1], 0)
+        out.append(acc)
+    return out
+
+
+def _pull_blocked_kernel(masks_ref, ft_ref, out_ref, *, sigma, kw):
+    tau = masks_ref.shape[1]
+    words = packed_marks(masks_ref[...].astype(jnp.int32), ft_ref[...],
+                         sigma=sigma, kw=kw)
+    for w, acc in enumerate(words):
+        out_ref[:, w * tau:(w + 1) * tau] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("sigma", "interpret"))
@@ -44,23 +66,21 @@ def pull_ms_packed(
 ) -> jax.Array:
     """marks (N_q, tau, kw) uint32 — packed pull for queued VSSs."""
     n_q, tau = masks.shape
-    num_sets, sig, kw = f_packed.shape
-    assert sig == sigma
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_q,),
-        in_specs=[
-            pl.BlockSpec((1, tau), lambda i, v2r_: (i, 0)),
-            pl.BlockSpec((1, sigma, kw), lambda i, v2r_: (v2r_[i], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tau, kw), lambda i, v2r_: (i, 0, 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_pull_ms_packed_kernel, sigma=sigma),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_q, tau, kw), jnp.uint32),
+    kw = f_packed.shape[2]
+    assert f_packed.shape[1] == sigma
+    blk, (masks, ft) = pad_blocks(n_q, masks, frontier_tiles(f_packed, v2r))
+    n_blk = masks.shape[0]
+    out = pl.pallas_call(
+        functools.partial(_pull_blocked_kernel, sigma=sigma, kw=kw),
+        grid=(n_blk // blk,),
+        in_specs=[pl.BlockSpec((blk, tau), lambda i: (i, 0)),
+                  pl.BlockSpec((blk, kw * sigma), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((blk, kw * tau), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_blk, kw * tau), jnp.int32),
         interpret=interpret,
-    )(v2r, masks, f_packed)
+    )(masks, ft)
+    out = out[:n_q].reshape(n_q, kw, tau).transpose(0, 2, 1)
+    return jax.lax.bitcast_convert_type(out, jnp.uint32)
 
 
 def pull_ms_packed_ref(masks, f_tiles, sigma: int = 8):

@@ -8,19 +8,19 @@ bucket-padded to a power of two with a guaranteed padding VSS id — so the
 pull does ~ |Q| * tau work, the paper's queued/top-down scheduling (Eq. (6)
 left branch) applied to packed lanes.
 
-Per grid step i the kernel pulls, for VSS ``q = qids[i]`` with sigma-bit
-masks m:
+For bucket entry i the kernel pulls, for VSS ``q = qids[i]`` with
+sigma-bit masks m:
 
     marks[i, j, w] = OR_{b : m[j]_b = 1}  F_packed[v2r[q]*sigma + b, w]
 
-Both the mask row block and the parent frontier tile are selected through
-*scalar-prefetched* index arrays (``qids`` directly, ``v2r`` composed
-through it) — the double-indirection analogue of the ``virtualToReal``
-prefetch in kernels/pull_ms.py, here applied on the input side so neither
-the masks nor the frontier need a host-side gather.  Padding bucket slots
-name a padding VSS (zero masks, sentinel parent set), so they contribute
-no marks; the caller scatters with ``row_ids[qids]`` whose padding rows
-land in the sentinel vertex slots.
+The kernel is the blocked pull of :mod:`kernels.pull_ms_packed`: XLA
+gathers the queued rows (``masks[qids]`` and the parent tiles through
+``v2r[qids]``) on the device, so the grid blocks over queued VSSs with the
+slots on the lanes.  (Scalar-prefetching ``qids`` to pick one VSS per grid
+step needs ``(1, tau)`` blocks, which Mosaic refuses.)  Padding bucket
+slots name a padding VSS (zero masks, sentinel parent set), so they
+contribute no marks; the caller scatters with ``row_ids[qids]`` whose
+padding rows land in the sentinel vertex slots.
 """
 from __future__ import annotations
 
@@ -28,22 +28,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-
-def _pull_ms_packed_queued_kernel(qids_ref, v2r_ref, masks_ref, f_ref,
-                                  out_ref, *, sigma):
-    del qids_ref, v2r_ref  # consumed by the index maps only
-    mask = masks_ref[...][0]      # (tau,) uint8
-    f = f_ref[...][0]             # (sigma, kw) uint32
-    kw = f.shape[1]
-    acc = jnp.zeros((mask.shape[0], kw), jnp.uint32)
-    for b in range(sigma):
-        sel = ((mask >> b) & 1).astype(jnp.uint32)[:, None]  # (tau, 1)
-        # sel in {0,1}: 0-sel = all-ones / all-zeros word (multiply-free)
-        acc = acc | ((jnp.uint32(0) - sel) & f[b][None, :])
-    out_ref[...] = acc[None]
+from repro.kernels.pull_ms_packed import pull_ms_packed
 
 
 @functools.partial(jax.jit, static_argnames=("sigma", "interpret"))
@@ -57,26 +43,8 @@ def pull_ms_packed_queued(
     interpret: bool = False,
 ) -> jax.Array:
     """marks (B, tau, kw) uint32 — packed pull over the queued VSSs only."""
-    _, tau = masks.shape
-    _, sig, kw = f_packed.shape
-    assert sig == sigma
-    b_q = qids.shape[0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b_q,),
-        in_specs=[
-            pl.BlockSpec((1, tau), lambda i, qids_, v2r_: (qids_[i], 0)),
-            pl.BlockSpec((1, sigma, kw),
-                         lambda i, qids_, v2r_: (v2r_[qids_[i]], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tau, kw), lambda i, qids_, v2r_: (i, 0, 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_pull_ms_packed_queued_kernel, sigma=sigma),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b_q, tau, kw), jnp.uint32),
-        interpret=interpret,
-    )(qids, v2r, masks, f_packed)
+    return pull_ms_packed(masks[qids], f_packed, v2r[qids], sigma=sigma,
+                          interpret=interpret)
 
 
 def pull_ms_packed_queued_ref(masks, f_packed, v2r, qids, sigma: int = 8):

@@ -7,19 +7,14 @@ the visited words.  At ``kw = kappa/32`` words per lane row that is
 ``2 * N_q * tau * kw * 4`` bytes of marks traffic per level that exists only
 to connect the two grids.
 
-This kernel fuses them: one grid of ``n_rows + N_q*tau`` sequential steps,
-
-  * phase 1 (steps ``0..n_rows``): ``out[s] = v[s]``            (init copy)
-  * phase 2 (step ``n_rows + e``, ``e = q*tau + j``):
-        ``out[row_ids[q, j]] |= OR_{b : masks[q, j]_b = 1} F[v2r[q], b, :]``
-
-so each mark row is computed in registers from the mask byte and the parent
-frontier tile and ORed straight into the live output block — the marks
-array is never written.  Both indirections (``rows`` on the output side,
-``v2r`` composed through ``e // tau`` on the input side) ride scalar
-prefetch, exactly the §3.3 scatter pattern with the §3.2 pull inlined into
-phase 2.  TPU grid steps execute sequentially on a core, so duplicate
-destination rows read-modify-write in a well-defined order.
+This kernel fuses them over one grid of VSS blocks with the visited words
+resident in VMEM (the :mod:`kernels.scatter_or` machinery): step 0 loads
+``v``; every step computes its block's marks with the selective-OR pull
+(slots on the lanes, :func:`~repro.kernels.pull_ms_packed.packed_marks`),
+moves them to SMEM, and ORs each nonzero mark word into
+``out[row_ids[q, j]]`` — the marks array never reaches HBM.  Grid steps
+execute sequentially on a core, so duplicate destination rows
+read-modify-write in a well-defined order.
 
 The jnp twin composes the two kernels' references bit-for-bit; it is the
 CPU path of the serve engine's packed substrate (and the oracle in
@@ -34,28 +29,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pull_ms_packed import pull_ms_packed_ref
-from repro.kernels.scatter_or import scatter_or_ref
+from repro.kernels.pull_ms_packed import (frontier_tiles, packed_marks,
+                                          pull_ms_packed_ref)
+from repro.kernels.scatter_or import (copy_in_first_step, from_lane_rows,
+                                      pad_blocks, resident_scatter_call,
+                                      scatter_block, scatter_or_ref,
+                                      to_lane_rows)
 
 
-def _pull_scatter_kernel(rows_ref, v2r_ref, dest_ref, masks_ref, f_ref,
-                         out_ref, *, n_rows, sigma, tau):
-    del rows_ref, v2r_ref  # consumed by the index maps only
-    s = pl.program_id(0)
-    init_phase = s < n_rows
-    e = jnp.maximum(s - n_rows, 0)
-    j = e % tau                   # slot within the VSS
-    mask_row = masks_ref[...][0]  # (tau,) uint8
-    f = f_ref[...][0]             # (sigma, kw) uint32
-    m = jax.lax.dynamic_slice(mask_row, (j,), (1,))[0]
-    kw = f.shape[1]
-    acc = jnp.zeros((kw,), jnp.uint32)
-    for b in range(sigma):
-        sel = ((m >> b) & 1).astype(jnp.uint32)
-        # sel in {0,1}: 0-sel = all-ones / all-zeros word (multiply-free)
-        acc = acc | ((jnp.uint32(0) - sel) & f[b])
-    cur = out_ref[...]
-    out_ref[...] = jnp.where(init_phase, dest_ref[...], cur | acc[None])
+def _pull_scatter_kernel(v_hbm, masks_ref, ft_ref, rows_ref, out_ref,
+                         marks_v, marks_s, *, sigma, kw):
+    copy_in_first_step(v_hbm, out_ref)
+    tau = masks_ref.shape[1]
+    words = packed_marks(masks_ref[...].astype(jnp.int32), ft_ref[...],
+                         sigma=sigma, kw=kw)
+    for w, acc in enumerate(words):
+        marks_v[:, w * tau:(w + 1) * tau] = acc
+    pltpu.sync_copy(marks_v, marks_s)
+    scatter_block(out_ref, rows_ref, marks_s, kw=kw, width=tau)
 
 
 @functools.partial(jax.jit, static_argnames=("sigma", "interpret"))
@@ -71,43 +62,24 @@ def pull_scatter_ms_packed(
 ) -> jax.Array:
     """Returns ``v`` with the dense pull's marks OR-scattered in, without
     materializing the marks array (duplicate-safe)."""
-    n_rows, kw = v.shape
+    kw = v.shape[1]
     n_q, tau = masks.shape
-    _, sig, kw_f = f_packed.shape
-    assert sig == sigma and kw_f == kw
-    t = rows.shape[0]
-    assert t == n_q * tau
-
-    def dest_index(s, rows_, v2r_):
-        return (jnp.where(s < n_rows, s, 0), 0)
-
-    def masks_index(s, rows_, v2r_):
-        return (jnp.clip(s - n_rows, 0, t - 1) // tau, 0)
-
-    def f_index(s, rows_, v2r_):
-        return (v2r_[jnp.clip(s - n_rows, 0, t - 1) // tau], 0, 0)
-
-    def out_index(s, rows_, v2r_):
-        e = jnp.clip(s - n_rows, 0, t - 1)
-        return (jnp.where(s < n_rows, s, rows_[e]), 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_rows + t,),
-        in_specs=[
-            pl.BlockSpec((1, kw), dest_index),
-            pl.BlockSpec((1, tau), masks_index),
-            pl.BlockSpec((1, sigma, kw), f_index),
-        ],
-        out_specs=pl.BlockSpec((1, kw), out_index),
-    )
-    return pl.pallas_call(
-        functools.partial(_pull_scatter_kernel, n_rows=n_rows, sigma=sigma,
-                          tau=tau),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(v.shape, v.dtype),
-        interpret=interpret,
-    )(rows, v2r, v, masks, f_packed)
+    assert f_packed.shape[1:] == (sigma, kw)
+    assert rows.shape[0] == n_q * tau
+    blk, (masks, ft, rows2) = pad_blocks(
+        n_q, masks, frontier_tiles(f_packed, v2r), rows.reshape(n_q, tau))
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    out = resident_scatter_call(
+        functools.partial(_pull_scatter_kernel, sigma=sigma, kw=kw),
+        to_lane_rows(v), (masks, ft, rows2),
+        [pl.BlockSpec((blk, tau), lambda i: (i, 0)),
+         pl.BlockSpec((blk, kw * sigma), lambda i: (i, 0)),
+         smem((blk, tau), lambda i: (i, 0))],
+        grid=masks.shape[0] // blk,
+        scratch_shapes=[pltpu.VMEM((blk, kw * tau), jnp.int32),
+                        pltpu.SMEM((blk, kw * tau), jnp.int32)],
+        interpret=interpret)
+    return from_lane_rows(out, v.shape)
 
 
 def pull_scatter_ms_packed_ref(v, masks, f_packed, v2r, rows, sigma: int = 8):

@@ -34,11 +34,17 @@ def main():
                     help="check against the CPU oracle")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
+    import jax
+
     from repro.core import pipeline, ref_bfs, triangles
     from repro.data import graphs
 
     g = graphs.make(args.family, scale=args.scale, seed=args.seed)
-    print(f"graph {args.family} n={g.n} m={g.m}")
+    print(f"graph {args.family} n={g.n} m={g.m} "
+          f"backend={jax.default_backend()}")
 
     if args.workload == "triangles":
         t0 = time.perf_counter()
@@ -46,7 +52,10 @@ def main():
         print(f"triangles: {count}  ({time.perf_counter() - t0:.2f}s)")
         return
 
-    bl = pipeline.Blest.preprocess(g, reorder=args.reorder, use_pallas=False)
+    # the Pallas kernels on the TPU; the jnp references elsewhere (the
+    # Pallas interpreter is far too slow at launcher scales)
+    bl = pipeline.Blest.preprocess(
+        g, reorder=args.reorder, use_pallas=jax.default_backend() == "tpu")
     s = bl.stats
     print(f"preprocess: {s.algorithm} (scale_free={s.scale_free}) "
           f"compression={s.compression_ratio:.3f} u_div={s.u_div:.0f} "

@@ -201,6 +201,9 @@ def main():
                     help="check every result against the CPU oracle")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     from repro.core import ref_bfs
     from repro.core.switching import ETA_DEFAULT
     from repro.data import graphs
@@ -396,6 +399,14 @@ def main():
                           ref_bfs.bfs_levels(fleet[q.graph], q.source),
                           unreached=ref_bfs.UNREACHED,
                           graph=fleet[q.graph])
+        # a FAILED ticket or a degraded (graph, layout) pair is a fault the
+        # service absorbed; under --verify it fails the run, with its cause
+        faults = [f"ticket {int(t)} FAILED: {t.error}" for t in tickets
+                  if t.state == TicketState.FAILED]
+        faults += [f"degraded {pair}: {why}"
+                   for pair, why in sorted(h.degraded.items())]
+        if faults:
+            raise SystemExit("verify failed:\n  " + "\n  ".join(faults))
         print("verified against CPU oracle ✓")
 
 

@@ -67,7 +67,7 @@ all packed lanes:
 * ``queued`` — frontier-compacted: the union of active VSSs across lanes is
   expanded host-side (realPtrs ranges), bucket-padded to a power of two,
   and pulled via ``kernels/pull_ms_packed_queued.py`` (packed substrate,
-  scalar-prefetched double indirection, work ~ |Q| * tau) or an XLA
+  blocked over the device-gathered queued VSSs, work ~ |Q| * tau) or an XLA
   take-based path (byteplane; slice-compacted through ``_nz_ptrs`` on the
   jnp path, work ~ |active slices| — §11.2).
 
@@ -429,6 +429,10 @@ class GraphArtifacts:
     sharded: "mesh_mod.ShardedGraph | None" = None
     placement: tuple = ()
     per_device_bytes: dict | None = None
+    # set-up seconds on the host clock: reorder + BVSS + device transfer +
+    # tile prep (build_s), and the switching probe's traversals (probe_s)
+    build_s: float = 0.0
+    probe_s: float = 0.0
 
     @property
     def total_bytes(self) -> int:
@@ -465,6 +469,7 @@ def build_artifacts(name: str, g: Graph, *, reorder: str | None = None,
     verdict (§13.4) — factories taking one argument are only ever called
     when no tiles were requested."""
     config = config or BvssConfig()
+    t_build = time.perf_counter()
     if prebuilt is not None:
         # §17: the mesh build path already ran reorder + BVSS on host (it
         # needed the byte projection before deciding how to place) — do
@@ -484,6 +489,8 @@ def build_artifacts(name: str, g: Graph, *, reorder: str | None = None,
             tiles = mma_mod.prep_mma_tiles(bd)
         except Exception as e:  # noqa: BLE001 — any tile-prep error
             degraded = f"mma tile prep raised: {e!r}"
+    build_s = time.perf_counter() - t_build
+    t_probe = time.perf_counter()
     sw = None
     if probe:
         if probe_runner is not None:
@@ -495,6 +502,7 @@ def build_artifacts(name: str, g: Graph, *, reorder: str | None = None,
         else:
             sw = switching_mod.probe_switching_benefit(
                 bd, eta=eta, use_pallas=probe_use_pallas)
+    probe_s = time.perf_counter() - t_probe
     arrays = [bd.masks, bd.row_ids, bd.v2r, bd.real_ptrs]
     if bd.masks_packed is not bd.masks:  # aliased when tau % 4 != 0
         arrays.append(bd.masks_packed)
@@ -509,7 +517,8 @@ def build_artifacts(name: str, g: Graph, *, reorder: str | None = None,
     return GraphArtifacts(name=name, graph=g, bvss=b, bd=bd, perm=perm,
                           reorder=rr, switching=sw,
                           device_bytes=dev_bytes, aux_bytes=aux_bytes,
-                          mma=tiles, degraded=degraded)
+                          mma=tiles, degraded=degraded,
+                          build_s=build_s, probe_s=probe_s)
 
 
 class GraphCache:
@@ -1047,6 +1056,19 @@ class LaneState(NamedTuple):
     levels: jax.Array   # (n_ext, kappa) int32 — global level stamps
 
 
+class _GraphOperands(NamedTuple):
+    """The per-graph device arrays a runner's jitted steps read.  They are
+    passed as an argument: a jitted closure over them would embed them in
+    every program as constants (see ``BvssDevice``)."""
+
+    bd: BvssDevice
+    tiles: "mma_mod.MmaTiles | None"
+    # the slice-compacted byteplane substrate's slot list (§11.2)
+    nz_mask: jax.Array | None
+    nz_parent: jax.Array | None
+    nz_rows: jax.Array | None
+
+
 class _LaneRunner:
     """kappa MS-BFS lanes over one graph; jit-compiled level + reseed steps.
 
@@ -1091,8 +1113,8 @@ class _LaneRunner:
         self._active_fn = jax.jit(lambda f: (f != 0).any(axis=(1, 2)))
         self._real_ptrs = np.asarray(bd.real_ptrs)
         self._pad_vss = bd.num_vss  # a guaranteed padding VSS id
-        self._rows_flat = bd.row_ids.reshape(-1)  # fused-kernel scatter rows
         self._compact = self.substrate == "byteplane" and not use_pallas
+        nz = (None, None, None)
         if self._compact:
             # slice-compacted pulls (§11.2): the (num_vss_pad, tau) grid is
             # mostly padding (zero masks -> zero marks -> no-op scatter
@@ -1112,15 +1134,13 @@ class _LaneRunner:
             parent_c = np.append(np.asarray(bd.v2r)[nz_vss], bd.num_sets)
             rows_c = np.append(np.asarray(bd.row_ids)[nz_vss, nz_slot],
                                bd.n_pad)
-            self._nz_mask = jnp.asarray(mask_c)
-            self._nz_parent = jnp.asarray(parent_c.astype(np.int32))
-            self._nz_rows = jnp.asarray(rows_c.astype(np.int32))
+            nz = (jnp.asarray(mask_c), jnp.asarray(parent_c.astype(np.int32)),
+                  jnp.asarray(rows_c.astype(np.int32)))
             self._pad_slice = int(mask_c.size - 1)  # the sentinel entry
-        # megatick residency (DESIGN.md §11.1): per-set VSS counts for the
-        # on-device |Q|, the bucket-guard threshold (smallest |Q| whose
-        # padded bucket reaches the full sweep), and jitted drivers per
-        # (T, policy) pair
-        self._set_counts = bd.real_ptrs[1:] - bd.real_ptrs[:-1]
+        self._ops = _GraphOperands(bd, self._tiles, *nz)
+        # megatick residency (DESIGN.md §11.1): the bucket-guard threshold
+        # (smallest |Q| whose padded bucket reaches the full sweep), and
+        # jitted drivers per (T, policy) pair
         if bucket_size(1) >= bd.num_vss_pad:
             self._dense_guard = 0
         else:
@@ -1161,17 +1181,17 @@ class _LaneRunner:
         return frontier_planes(self.bd, v_or_diff)
 
     # ---- one level over all lanes -----------------------------------------
-    def _pull_scatter(self, v, f):
-        bd = self.bd
+    def _pull_scatter(self, g: "_GraphOperands", v, f):
+        bd = g.bd
         if self.substrate == "byteplane":
             if self._mma:
                 # §13.3 AND-OR/popcount fallback: the dense pull over the
                 # slice-compacted slots as one int8 counts matmul instead
                 # of the sigma-pass OR ladder — marks are (counts > 0)
-                ft = f[self._nz_parent]  # (S, sigma, kappa) uint8 planes
+                ft = f[g.nz_parent]  # (S, sigma, kappa) uint8 planes
                 marks = mma_mod.pull_mma_byteplane_ref(
-                    self._tiles.nz_planes[:, None, :], ft)[:, 0]
-                return v.at[self._nz_rows].max(marks)
+                    g.tiles.nz_planes[:, None, :], ft)[:, 0]
+                return v.at[g.nz_rows].max(marks)
             if self.use_pallas:
                 marks = ops.pull_ms(bd.masks, f, bd.v2r, sigma=bd.sigma,
                                     use_pallas=True)
@@ -1181,19 +1201,18 @@ class _LaneRunner:
             # marks and scatter rows over the static nonzero-slice list
             # only — zero-mask slots could never contribute, and XLA CPU
             # scatter cost is linear in rows
-            ft = f[self._nz_parent]  # (S, sigma, kappa) uint8 bit-planes
-            marks = jnp.zeros((self._nz_mask.shape[0], self.kappa),
-                              jnp.uint8)
+            ft = f[g.nz_parent]  # (S, sigma, kappa) uint8 bit-planes
+            marks = jnp.zeros((g.nz_mask.shape[0], self.kappa), jnp.uint8)
             for b in range(bd.sigma):
-                sel = ((self._nz_mask >> b) & 1)[:, None]
+                sel = ((g.nz_mask >> b) & 1)[:, None]
                 marks = marks | (sel * ft[:, b])
-            return v.at[self._nz_rows].max(marks)
+            return v.at[g.nz_rows].max(marks)
         if self._mma:
-            # §13.2 fused MMA pull+scatter: each mark row is a
-            # (1, sigma) x (sigma, kappa) binary product ORed into the
+            # §13.2 fused MMA pull+scatter: each VSS's marks are one
+            # (kappa, sigma) x (sigma, tau) binary product ORed into the
             # live visited words (kernel), or — jnp twin — one batched
             # counts matmul + duplicate-safe scatter-add
-            t = self._tiles
+            t = g.tiles
             if self.use_pallas:
                 return mma_mod.pull_scatter_mma_ms_packed(
                     v, t.a_planes, f, t.v2r, t.rows, sigma=bd.sigma,
@@ -1203,14 +1222,15 @@ class _LaneRunner:
         # fused pull+scatter (DESIGN.md §11.2): marks are computed in
         # registers and ORed straight into the visited words — no
         # (N_q*tau, kw) marks array between the pull and the scatter
+        rows_flat = bd.row_ids.reshape(-1)
         if self.use_pallas:
-            return pull_scatter_ms_packed(v, bd.masks, f, bd.v2r,
-                                          self._rows_flat, sigma=bd.sigma,
+            return pull_scatter_ms_packed(v, bd.masks, f, bd.v2r, rows_flat,
+                                          sigma=bd.sigma,
                                           interpret=self._interpret)
-        return pull_scatter_ms_packed_ref(v, bd.masks, f, bd.v2r,
-                                          self._rows_flat, sigma=bd.sigma)
+        return pull_scatter_ms_packed_ref(v, bd.masks, f, bd.v2r, rows_flat,
+                                          sigma=bd.sigma)
 
-    def _pull_scatter_queued(self, v, f, qids):
+    def _pull_scatter_queued(self, g: "_GraphOperands", v, f, qids):
         """Frontier-compacted pull+scatter over the active list only
         (DESIGN.md §10.1): work ~ |Q| * tau instead of N_v * tau — or
         ~ |active slices| on the slice-compacted path, where ``qids`` are
@@ -1218,18 +1238,18 @@ class _LaneRunner:
         The MMA layout shares this path unchanged: queued sweeps are
         sparse gathers, which the bit-MMA formulation does not help
         (DESIGN.md §13.2)."""
-        bd = self.bd
+        bd = g.bd
         if self.substrate == "byteplane":
             if self._compact:
                 # slice-compacted queued pull (§11.2): gather the active
                 # slices' mask bytes / parent tiles / rows directly
-                mask_q = self._nz_mask[qids]        # (B,) uint8
-                ft = f[self._nz_parent[qids]]       # (B, sigma, kappa)
+                mask_q = g.nz_mask[qids]            # (B,) uint8
+                ft = f[g.nz_parent[qids]]           # (B, sigma, kappa)
                 marks = jnp.zeros((qids.shape[0], self.kappa), jnp.uint8)
                 for b in range(bd.sigma):
                     sel = ((mask_q >> b) & 1)[:, None]
                     marks = marks | (sel * ft[:, b])
-                return v.at[self._nz_rows[qids]].max(marks)
+                return v.at[g.nz_rows[qids]].max(marks)
             # XLA take-based queued path: gather the queued masks/rows/parent
             # tiles, then the same OR-of-selected-planes pull as dense.  (The
             # MXU byteplane kernel is deliberately not given a queued twin —
@@ -1273,21 +1293,21 @@ class _LaneRunner:
             levels=jnp.where(bits == 1, ell, state.levels),
         ), new_lane
 
-    def _level(self, state: LaneState, ell):
+    def _level(self, g: "_GraphOperands", state: LaneState, ell):
         """Advance every lane one dense level; returns (state', new_per_lane)."""
-        v_next = self._pull_scatter(state.v, state.f)
+        v_next = self._pull_scatter(g, state.v, state.f)
         return self._finish_level(state, v_next, ell)
 
-    def _level_queued(self, state: LaneState, ell, qids):
+    def _level_queued(self, g: "_GraphOperands", state: LaneState, ell, qids):
         """Advance every lane one queued level over the active VSSs only."""
-        v_next = self._pull_scatter_queued(state.v, state.f, qids)
+        v_next = self._pull_scatter_queued(g, state.v, state.f, qids)
         return self._finish_level(state, v_next, ell)
 
     def level(self, state: LaneState, ell: int):
-        return self._level_fn(state, jnp.int32(ell))
+        return self._level_fn(self._ops, state, jnp.int32(ell))
 
     def level_queued(self, state: LaneState, ell: int, qids: np.ndarray):
-        return self._level_queued_fn(state, jnp.int32(ell),
+        return self._level_queued_fn(self._ops, state, jnp.int32(ell),
                                      jnp.asarray(qids, jnp.int32))
 
     def active_set_mask(self, f) -> np.ndarray:
@@ -1368,13 +1388,16 @@ class _LaneRunner:
             self._megatick_fns[key] = fn
         reach_dev = (jnp.asarray(reach, jnp.int32) if policy_on
                      else self._reach_zero)
-        return fn(state, reach_dev, jnp.int32(ell0),
+        return fn(self._ops, state, reach_dev, jnp.int32(ell0),
                   jnp.asarray(active, bool),
                   jnp.asarray(admitted_at, jnp.int32))
 
-    def _megatick(self, state: LaneState, reach, ell0, active, admitted_at,
-                  *, T: int, policy_on: bool, eta: float):
-        bd = self.bd
+    def _megatick(self, g: "_GraphOperands", state: LaneState, reach, ell0,
+                  active, admitted_at, *, T: int, policy_on: bool,
+                  eta: float):
+        bd = g.bd
+        # per-set VSS counts for the on-device |Q|
+        set_counts = bd.real_ptrs[1:] - bd.real_ptrs[:-1]
 
         def cond(carry):
             st, reach, tick, done, hist = carry
@@ -1391,7 +1414,7 @@ class _LaneRunner:
                 # host mirror is int64 for the same reason), while float32
                 # merely rounds.
                 af = self._active_fn(st.f)[: bd.num_sets]
-                q_len = jnp.where(af, self._set_counts, 0).sum()
+                q_len = jnp.where(af, set_counts, 0).sum()
                 unvisited = jnp.where(
                     active, (bd.n - reach).astype(jnp.float32), 0.0).sum()
                 dense = unvisited < eta * q_len.astype(jnp.float32)
@@ -1402,7 +1425,7 @@ class _LaneRunner:
         def body(carry):
             st, reach, tick, done, hist = carry
             ell = ell0 + tick + 1
-            st, new_lane = self._level(st, ell)
+            st, new_lane = self._level(g, st, ell)
             # new counts are monotone-absorbing at zero (an empty lane
             # frontier stays empty), so |= is exact
             done = done | (active & ((new_lane == 0)

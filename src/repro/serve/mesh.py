@@ -47,7 +47,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.core import blest, reorder as reorder_mod
@@ -325,7 +324,7 @@ class ShardedLaneRunner:
 
         shard = PartitionSpec(AXIS)
         repl = PartitionSpec()
-        sm = functools.partial(shard_map, mesh=self.mesh, check_rep=False)
+        sm = functools.partial(jax.shard_map, mesh=self.mesh, check_vma=False)
         self._level_fn = jax.jit(sm(
             self._level_shard,
             in_specs=(shard, repl, shard, shard, shard, shard, repl),
@@ -417,7 +416,7 @@ class ShardedLaneRunner:
             shard = PartitionSpec(AXIS)
             repl = PartitionSpec()
             fn = jax.jit(functools.partial(
-                shard_map, mesh=self.mesh, check_rep=False)(
+                jax.shard_map, mesh=self.mesh, check_vma=False)(
                 functools.partial(self._megatick_shard, T=int(ticks)),
                 in_specs=(shard, repl, shard, shard, shard, shard,
                           repl, repl, repl),

@@ -409,8 +409,8 @@ def verify_result(res: BfsResult, query: BfsQuery, levels: np.ndarray,
         assert res.in_mis == bool(m[query.source]), where
         assert res.mis_size == int(m.sum()), where
     elif query.kind == KIND_TPV:
-        t = _graph_memo("tpv", graph,
-                        triangles_mod.triangles_per_vertex_ref)
-        assert res.triangles == int(t[query.source]), where
+        csr = _graph_memo("sym_csr", graph, lambda g: g.symmetrized().csr)
+        assert res.triangles == triangles_mod.triangles_of_vertex_ref(
+            csr, query.source), where
     else:
         raise ValueError(f"no oracle check for custom kind {query.kind!r}")

@@ -94,6 +94,20 @@ def test_levels_are_valid_bfs_labelling(suite):
     assert ref_bfs.bfs_parents_valid(g, 0, got)
 
 
+@pytest.mark.parametrize("seed", range(4))
+def test_gather_ranges_matches_loop(seed):
+    """The oracle's vectorized neighbour gather equals concatenating the
+    CSR ranges one by one, empty ranges included."""
+    rng = np.random.default_rng(seed)
+    cols = rng.integers(0, 1 << 20, 5000).astype(np.int32)
+    starts = rng.integers(0, 5000, 1500)
+    lens = rng.integers(0, 9, 1500) * (rng.random(1500) < 0.8)  # some empty
+    ends = np.minimum(starts + lens, 5000)
+    want = np.concatenate([cols[s:e] for s, e in zip(starts, ends)])
+    got = ref_bfs._gather_ranges(cols, starts, ends, int((ends - starts).sum()))
+    assert got.dtype == want.dtype and np.array_equal(got, want)
+
+
 # --------------------------------------------------------------------------
 # property: driver equivalence on random digraphs (hypothesis, optional)
 # --------------------------------------------------------------------------

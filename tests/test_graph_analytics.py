@@ -102,6 +102,10 @@ def test_tpv_matches_dense_ref(seed):
     rng = np.random.default_rng(seed + 2)
     for v in rng.integers(0, g.n, 4):
         assert triangles.triangles_of_vertex(st, int(v)) == int(ref[v])
+    # the sparse single-vertex oracle (verify_result's) == the dense one
+    csr = g.symmetrized().csr
+    assert [triangles.triangles_of_vertex_ref(csr, v)
+            for v in range(g.n)] == ref.tolist(), seed
 
 
 # ------------------------------------------- engine-in-the-loop parity ----

@@ -5,6 +5,8 @@ mid-flight admission landing inside a megatick window; the fused
 pull+scatter kernel matches its composed references; the serve-aware
 probe replaces the single-source proxy; and the extraction gather /
 host-side reach satellites stay exact."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -207,3 +209,37 @@ def test_extraction_gather_buckets(duo):
         got = r.gather_level_cols(state.levels, cols)
         assert got.shape == (art.bd.n, len(cols))
         assert (got == full[:, cols]).all()
+
+
+# ------------------------------------------------ graph arrays as operands --
+@pytest.mark.parametrize("layout", ["byteplane", "packed", "mma"])
+def test_runner_programs_take_graph_as_argument(layout):
+    """The lane runner's jitted steps take the graph's arrays as arguments.
+    A closure over them embeds them in every program as constants: at
+    scale 20 that is minutes of compilation per program and a device copy
+    per executable."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.serve.bfs_engine import _LaneRunner
+
+    art = build_artifacts("g", graphs.make("urand", scale=12, seed=0))
+    r = _LaneRunner(art.bd, 32, layout=layout, use_pallas=False)
+    st = r.init_state()
+    ell = jnp.int32(1)
+    texts = [
+        r._level_fn.lower(r._ops, st, ell).as_text(),
+        r._level_queued_fn.lower(r._ops, st, ell,
+                                 jnp.zeros(8, jnp.int32)).as_text(),
+        jax.jit(functools.partial(r._megatick, T=4, policy_on=True,
+                                  eta=10.0)).lower(
+            r._ops, st, jnp.zeros(32, jnp.int32), ell,
+            jnp.ones(32, bool), jnp.zeros(32, jnp.int32)).as_text()]
+    graph_bytes = min(int(x.nbytes) for x in jax.tree.leaves(r._ops)
+                      if x.size > 1024)
+    for text in texts:
+        consts = re.findall(r'dense<"0x([0-9A-F]*)"', text)
+        biggest = max((len(c) // 2 for c in consts), default=0)
+        assert biggest < graph_bytes // 4, (layout, biggest, graph_bytes)

@@ -271,7 +271,27 @@ def test_health_reports_mesh_occupancy():
 
 
 # ------------------------------------------------ launcher (--health-json) -
-def test_launcher_health_json(tmp_path, monkeypatch):
+@pytest.fixture
+def launcher_cache(tmp_path, monkeypatch):
+    """The launcher turns JAX's persistent compilation cache on for the
+    whole process: point its default directory at a temporary one and
+    restore the cache settings afterwards."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.launch import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(compile_cache, "DEFAULT_DIR", tmp_path / "jax_cache")
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs")
+    prev = {k: getattr(jax.config, k) for k in names}
+    yield tmp_path / "jax_cache"
+    for k, v in prev.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+
+
+def test_launcher_health_json(tmp_path, monkeypatch, launcher_cache):
     from repro.launch import serve_bfs
 
     path = tmp_path / "health.json"
@@ -284,3 +304,27 @@ def test_launcher_health_json(tmp_path, monkeypatch):
     assert snap["queue_depths"] == {} and snap["in_flight"] == 0
     assert "device_bytes" in snap and "device_queue_depth" in snap
     assert "ts" in snap
+    # the launcher compiled through the persistent cache it switched on
+    assert any(launcher_cache.iterdir())
+
+
+@pytest.mark.parametrize("fault", ["degraded", "FAILED"])
+def test_launcher_verify_fails_on_absorbed_fault(fault, monkeypatch,
+                                                 launcher_cache):
+    """``--verify`` exits non-zero, naming the cause, when the service
+    absorbed a fault: a degraded (graph, layout) pair or a FAILED ticket."""
+    import repro.serve.bfs_engine as engine_mod
+    from repro.launch import serve_bfs
+
+    def boom(*args, **kwargs):
+        raise PermanentBuildError("injected fault")
+
+    if fault == "degraded":
+        monkeypatch.setattr(engine_mod.mma_mod, "prep_mma_tiles", boom)
+    else:
+        monkeypatch.setattr(engine_mod, "build_artifacts", boom)
+    monkeypatch.setattr(sys, "argv", [
+        "serve_bfs", "--families", "kron", "--scale", "5", "--requests",
+        "4", "--layout", "mma", "--switching", "off", "--verify"])
+    with pytest.raises(SystemExit, match=f"(?s)verify failed:.*{fault}"):
+        serve_bfs.main()
