@@ -189,6 +189,7 @@ from repro.kernels.pull_scatter_ms_packed import (
 from repro.kernels.scatter_or import scatter_or, scatter_or_ref
 from repro.serve import lifecycle as lifecycle_mod
 from repro.serve import mesh as mesh_mod
+from repro.serve import spans as spans_mod
 from repro.serve import workloads as workloads_mod
 from repro.serve.workloads import (  # re-exported: the request/result
     KIND_BFS, KIND_CLOSENESS, KIND_DISTANCE, KIND_REACH,  # noqa: F401
@@ -199,6 +200,16 @@ SWITCHING_MODES = ("auto", "on", "off")
 SCHEDULERS = ("rr", "serial")
 LAYOUTS = ("auto", "packed", "byteplane", "mma")
 OVERLOAD_POLICIES = ("reject", "defer")
+# the device -> host read-backs of the serving path, each with whether the
+# read launches a gather program of its own (the others read the output of
+# a level or a megatick window)
+SYNC_SITES = {"active_mask": True, "new_lane": False, "megatick": False,
+              "watch": True, "gather_cols": True}
+# the host spans of the serving path (serve/spans.py); their self seconds
+# are ``stats["host_s:<name>"]``
+SPAN_NAMES = ("serve.step", "serve.housekeep", "serve.tick", "serve.admit",
+              "serve.decide", "serve.expand", "serve.dispatch", "serve.hooks",
+              "serve.finish", *("serve.sync." + s for s in SYNC_SITES))
 
 
 # ---------------------------------------------------------------------------
@@ -1678,51 +1689,80 @@ class _GraphSession:
         clear[stale] = True
         self.state = self.runner.reseed(
             self.state, clear, np.full(kappa, -1, np.int32), self.ell)
+        eng.stats["dispatches"] += 1
+
+    # ---- device -> host read-backs ----------------------------------------
+    def _read(self, site: str, fn, *args) -> np.ndarray:
+        """``fn(*args)``, one device -> host read-back of the serving path
+        (a site of ``SYNC_SITES``): timed as the span ``serve.sync.<site>``
+        and counted in ``syncs:<site>`` and ``host_syncs``, so that
+        ``host_syncs`` is always the sum of the site counts."""
+        stats = self.engine.stats
+        with self.engine._spans("serve.sync." + site):
+            out = fn(*args)
+        stats["syncs:" + site] += 1
+        stats["host_syncs"] += 1
+        if SYNC_SITES[site]:
+            stats["dispatches"] += 1
+        return out
 
     # ---- one scheduling tick ----------------------------------------------
     def tick(self) -> None:
+        """One level or one megatick window for every lane, as the span
+        ``serve.tick`` (its arguments: graph, level, mode)."""
+        with self.engine._spans("serve.tick", graph=self.name,
+                                level=self.ell) as ann:
+            ann.set_metadata(mode=self._tick())
+
+    def _tick(self) -> str | None:
+        """The body of :meth:`tick`; returns the mode of its last level
+        (``dense``, ``queued`` or ``megatick``), None with no lane."""
         eng = self.engine
         runner, art, kappa = self.runner, self.art, eng.kappa
         queue, lanes = self.queue, self.lanes
-        self._reclaim_lanes()
-        # ---- admission: refill free lanes from the queue -----------------
-        free = [i for i in range(kappa) if lanes[i] is None]
-        if free and queue:
-            self.meta_dev = None
-            self.watch_dev = None
-            clear = np.zeros(kappa, bool)
-            new_src = np.full(kappa, -1, np.int32)
-            now = eng._clock()
-            for i in free:
-                q = None
-                # §16.1 seeding-time check: pop until a request that can
-                # still make its deadline (expired ones shed here)
-                while queue:
-                    cand = queue.popleft()
-                    if eng._seed_ok(cand, now):
-                        q = cand
+        span = eng._spans
+        with span("serve.admit"):
+            self._reclaim_lanes()
+            # ---- admission: refill free lanes from the queue -------------
+            free = [i for i in range(kappa) if lanes[i] is None]
+            if free and queue:
+                self.meta_dev = None
+                self.watch_dev = None
+                clear = np.zeros(kappa, bool)
+                new_src = np.full(kappa, -1, np.int32)
+                now = eng._clock()
+                for i in free:
+                    q = None
+                    # §16.1 seeding-time check: pop until a request that
+                    # can still make its deadline (expired ones shed here)
+                    while queue:
+                        cand = queue.popleft()
+                        if eng._seed_ok(cand, now):
+                            q = cand
+                            break
+                    if q is None:
                         break
-                if q is None:
-                    break
-                wl = eng._workloads[q.kind]
-                lanes[i] = q
-                self.wl[i] = wl
-                self.accs[i] = (workloads_mod.LaneAccum()
-                                if wl.has_accumulate else None)
-                self.admitted_at[i] = self.ell
-                self.far64[i] = 0
-                self.reach_host[i] = 1  # the seeded source is visited
-                self.watch_ids[i] = (art.perm[q.target]
-                                     if wl.watches_target else -1)
-                self.tl[i] = UNREACHED
-                clear[i] = True
-                new_src[i] = art.perm[q.source]
-                eng._lane_admitted(q, now)
-                if self.ell > 0:
-                    eng.stats["admissions_midflight"] += 1
-            self.state = runner.reseed(self.state, clear, new_src, self.ell)
+                    wl = eng._workloads[q.kind]
+                    lanes[i] = q
+                    self.wl[i] = wl
+                    self.accs[i] = (workloads_mod.LaneAccum()
+                                    if wl.has_accumulate else None)
+                    self.admitted_at[i] = self.ell
+                    self.far64[i] = 0
+                    self.reach_host[i] = 1  # the seeded source is visited
+                    self.watch_ids[i] = (art.perm[q.target]
+                                         if wl.watches_target else -1)
+                    self.tl[i] = UNREACHED
+                    clear[i] = True
+                    new_src[i] = art.perm[q.source]
+                    eng._lane_admitted(q, now)
+                    if self.ell > 0:
+                        eng.stats["admissions_midflight"] += 1
+                self.state = runner.reseed(self.state, clear, new_src,
+                                           self.ell)
+                eng.stats["dispatches"] += 1
         if all(q is None for q in lanes):
-            return
+            return None
         active_arr = np.fromiter((q is not None for q in lanes), bool, kappa)
         # ---- megatick window: up to T fused dense levels (§11.1) ---------
         # windows run when this graph's queue is drained; under backlog
@@ -1733,12 +1773,13 @@ class _GraphSession:
             if self.meta_dev is None:
                 self.meta_dev = (jnp.asarray(active_arr),
                                  jnp.asarray(self.admitted_at, jnp.int32))
-            self.state, hist = runner.megatick(
-                self.state, self.reach_host.astype(np.int32), self.ell,
-                self.meta_dev[0], self.meta_dev[1], eng.eta,
-                ticks=eng.megatick, policy_on=self.policy_on)
-            hist = np.asarray(hist)
-            eng.stats["host_syncs"] += 1
+            with span("serve.dispatch"):
+                self.state, hist = runner.megatick(
+                    self.state, self.reach_host.astype(np.int32), self.ell,
+                    self.meta_dev[0], self.meta_dev[1], eng.eta,
+                    ticks=eng.megatick, policy_on=self.policy_on)
+            eng.stats["dispatches"] += 1
+            hist = self._read("megatick", np.asarray, hist)
             # unexecuted rows stay -1: the one transfer above carries
             # both the executed tick count and every level's counts
             ticks = int((hist[:, 0] >= 0).sum())
@@ -1759,17 +1800,18 @@ class _GraphSession:
                 # window
                 if self._finish_tick(hist[ticks - 1], tl):
                     self.meta_dev = None
-                    return  # freed lanes: admit before the next window
+                    return "megatick"  # freed lanes: admit first
                 if ticks == eng.megatick:
-                    return  # window exhausted with every lane active
+                    return "megatick"  # window exhausted, every lane active
             # the window stopped short of T with no lane finished: the
             # on-device Eq. (6) verdict was queued — run that one level
             # host-side with the §10 bucketed machinery, and stay on
             # the per-level path while the verdict keeps being queued
             mode = "queued"
             self.prefer_host = True
-            active_mask = runner.active_set_mask(self.state.f)
-            eng.stats["host_syncs"] += 1
+            with span("serve.decide"):
+                active_mask = self._read("active_mask", runner.active_set_mask,
+                                         self.state.f)
         else:
             # ---- mode decision over the aggregate frontier (§10.2) -------
             # counts first, ids later: the decision needs only |Q|; the
@@ -1778,33 +1820,41 @@ class _GraphSession:
             mode = "dense"
             active_mask = None
             if self.policy_on:
-                active_mask = runner.active_set_mask(self.state.f)
-                eng.stats["host_syncs"] += 1
-                q_len = runner.queue_len(active_mask)
-                unvisited = int(np.where(active_arr,
-                                         art.graph.n - self.reach_host,
-                                         0).sum())
-                mode = switching_mod.decide_mode(unvisited, q_len, eng.eta)
-                # bucket guard: a padded queue as large as the full VSS
-                # sweep can only lose to dense (gather overhead, no
-                # savings)
-                if bucket_size(q_len) >= art.bd.num_vss_pad:
-                    mode = "dense"
+                with span("serve.decide"):
+                    active_mask = self._read(
+                        "active_mask", runner.active_set_mask, self.state.f)
+                    q_len = runner.queue_len(active_mask)
+                    unvisited = int(np.where(active_arr,
+                                             art.graph.n - self.reach_host,
+                                             0).sum())
+                    mode = switching_mod.decide_mode(unvisited, q_len,
+                                                     eng.eta)
+                    # bucket guard: a padded queue as large as the full
+                    # VSS sweep can only lose to dense (gather overhead,
+                    # no savings)
+                    if bucket_size(q_len) >= art.bd.num_vss_pad:
+                        mode = "dense"
             if mode == "dense":
                 self.prefer_host = False  # dense again: windows may resume
         # ---- one level for every lane ------------------------------------
         self.ell += 1
         if mode == "queued":
-            qids = runner.active_vss(active_mask)
-            self.state, new_lane = runner.level_queued(
-                self.state, self.ell, runner.bucket_qids(qids))
+            with span("serve.expand"):
+                qids = runner.active_vss(active_mask)
+                rows = runner.bucket_qids(qids)
+            eng.stats["queued_vss"] += qids.size
+            eng.stats["queued_rows"] += rows.size
+            with span("serve.dispatch"):
+                self.state, new_lane = runner.level_queued(
+                    self.state, self.ell, rows)
             eng.stats["levels_queued"] += 1
         else:
-            self.state, new_lane = runner.level(self.state, self.ell)
+            with span("serve.dispatch"):
+                self.state, new_lane = runner.level(self.state, self.ell)
             eng.stats["levels_dense"] += 1
         eng.stats["levels"] += 1
-        nl = np.asarray(new_lane)
-        eng.stats["host_syncs"] += 1
+        eng.stats["dispatches"] += 1
+        nl = self._read("new_lane", np.asarray, new_lane)
         self.reach_host += nl
         self.far64 += (self.ell - self.admitted_at).astype(np.int64) * nl
         self._run_hooks(nl[None, :].astype(np.int64),
@@ -1812,6 +1862,7 @@ class _GraphSession:
         tl = self._watch_tick()
         if self._finish_tick(nl, tl):
             self.meta_dev = None
+        return mode
 
     # ---- per-level workload hooks (§12.3) ---------------------------------
     def _run_hooks(self, counts: np.ndarray, ells: np.ndarray) -> None:
@@ -1819,15 +1870,16 @@ class _GraphSession:
         levels: ``counts`` is (T, kappa) new-vertex counts at global
         levels ``ells``.  Lanes of hook-less workloads (all built-ins)
         never enter the loop, so the hot path stays vectorized."""
-        if not any(a is not None for a in self.accs):
-            return
-        for i in range(self.engine.kappa):
-            acc = self.accs[i]
-            if acc is None or self.lanes[i] is None:
-                continue
-            wl, a0 = self.wl[i], int(self.admitted_at[i])
-            for t in range(counts.shape[0]):
-                wl.accumulate(acc, int(ells[t]) - a0, int(counts[t, i]))
+        with self.engine._spans("serve.hooks"):
+            if not any(a is not None for a in self.accs):
+                return
+            for i in range(self.engine.kappa):
+                acc = self.accs[i]
+                if acc is None or self.lanes[i] is None:
+                    continue
+                wl, a0 = self.wl[i], int(self.admitted_at[i])
+                for t in range(counts.shape[0]):
+                    wl.accumulate(acc, int(ells[t]) - a0, int(counts[t, i]))
 
     # ---- watched targets (§12.3) ------------------------------------------
     def _watch_tick(self) -> np.ndarray | None:
@@ -1841,8 +1893,8 @@ class _GraphSession:
         if self.watch_dev is None:
             self.watch_dev = jnp.asarray(
                 np.maximum(self.watch_ids, 0).astype(np.int32))
-        self.tl = self.runner.watch_levels(self.state.levels, self.watch_dev)
-        self.engine.stats["host_syncs"] += 1
+        self.tl = self._read("watch", self.runner.watch_levels,
+                             self.state.levels, self.watch_dev)
         return self.tl
 
     # ---- per-lane early exit ----------------------------------------------
@@ -1851,34 +1903,36 @@ class _GraphSession:
         window): frontier empty, diameter bound hit, or — distance lanes —
         the watched target's bit lit (§12.3); True iff any lane freed."""
         eng, art = self.engine, self.art
-        done = [i for i in range(eng.kappa) if self.lanes[i] is not None
-                and (nl[i] == 0
-                     or self.ell - self.admitted_at[i] >= art.bd.n_ext
-                     or (tl is not None and self.watch_ids[i] >= 0
-                         and tl[i] != UNREACHED))]
-        if not done:
-            return False
-        self._extract(done)
-        for i in done:
-            self.lanes[i] = None
-            self.wl[i] = None
-            self.accs[i] = None
-            self.watch_ids[i] = -1
-        self.watch_dev = None
-        # a lane freed with a non-empty frontier (watched-target early
-        # exit; in principle the diameter bound too) would keep
-        # traversing in its column and feed the dead frontier into the
-        # Eq. (6) aggregate / queued expansions until re-seeded — wipe it
-        # now (reseed with src=-1 clears without seeding); the common
-        # frontier-empty exit (nl == 0) skips the extra dispatch
-        live = [i for i in done if nl[i] != 0]
-        if live:
-            clear = np.zeros(eng.kappa, bool)
-            clear[live] = True
-            self.state = self.runner.reseed(
-                self.state, clear, np.full(eng.kappa, -1, np.int32),
-                self.ell)
-        return True
+        with eng._spans("serve.finish"):
+            done = [i for i in range(eng.kappa) if self.lanes[i] is not None
+                    and (nl[i] == 0
+                         or self.ell - self.admitted_at[i] >= art.bd.n_ext
+                         or (tl is not None and self.watch_ids[i] >= 0
+                             and tl[i] != UNREACHED))]
+            if not done:
+                return False
+            self._extract(done)
+            for i in done:
+                self.lanes[i] = None
+                self.wl[i] = None
+                self.accs[i] = None
+                self.watch_ids[i] = -1
+            self.watch_dev = None
+            # a lane freed with a non-empty frontier (watched-target early
+            # exit; in principle the diameter bound too) would keep
+            # traversing in its column and feed the dead frontier into the
+            # Eq. (6) aggregate / queued expansions until re-seeded — wipe
+            # it now (reseed with src=-1 clears without seeding); the
+            # common frontier-empty exit (nl == 0) skips the extra dispatch
+            live = [i for i in done if nl[i] != 0]
+            if live:
+                clear = np.zeros(eng.kappa, bool)
+                clear[live] = True
+                self.state = self.runner.reseed(
+                    self.state, clear, np.full(eng.kappa, -1, np.int32),
+                    self.ell)
+                eng.stats["dispatches"] += 1
+            return True
 
     def _extract(self, done: list[int]) -> None:
         eng, art = self.engine, self.art
@@ -1891,8 +1945,8 @@ class _GraphSession:
         lv_done = [i for i in done if self.wl[i].needs_levels]
         cols = {}
         if lv_done:
-            arr = self.runner.gather_level_cols(self.state.levels, lv_done)
-            eng.stats["host_syncs"] += 1
+            arr = self._read("gather_cols", self.runner.gather_level_cols,
+                             self.state.levels, lv_done)
             # one vectorized admission-offset subtraction + permutation for
             # every finished column (a per-lane loop here was measurable)
             lv = np.where(arr != UNREACHED,
@@ -2162,7 +2216,12 @@ class BfsEngine:
             "rejected": 0, "deferred": 0,
             "expired": 0, "cancelled": 0,
             "deadline_misses": 0, "degraded": 0,
+            # device programs launched while serving; the active VSSs of
+            # queued levels and the bucket rows dispatched for them
+            "dispatches": 0, "queued_vss": 0, "queued_rows": 0,
+            **{"syncs:" + site: 0 for site in SYNC_SITES},
         }
+        self._spans = spans_mod.Spans(self.stats, SPAN_NAMES)
 
     # ---- registration / admission -----------------------------------------
     def register_graph(self, name: str, graph: Graph, *,
@@ -2534,27 +2593,29 @@ class BfsEngine:
         sense and now also in the *build* sense: one bounded slice of
         work per call, never a synchronous artifact build (§14.3), so a
         caller can interleave submission and pumping in its own loop."""
-        self._poll_builds()
-        self._promote_deferred()
-        self._open_sessions()
-        if self._sessions:
-            name = self._schedule()
-            sess = self._sessions[name]
-            try:
-                sess.tick()
-            except Exception as exc:  # noqa: BLE001 — §16.4 degradation
-                if self._resolve_layout(sess.art) == self._base_layout():
-                    raise  # nothing to fall back to; stay loud (§15.3)
-                self._handle_session_fault(name, sess, exc)
-            else:
-                self.stats["ticks"] += 1
-                if (self._last_scheduled not in (None, name)
-                        and len(self._sessions) > 1):
-                    self.stats["session_switches"] += 1
-                self._last_scheduled = name
-                if sess.idle:
-                    self._close_session(name)
-        done, self._completed = self._completed, []
+        with self._spans("serve.step"):
+            with self._spans("serve.housekeep"):
+                self._poll_builds()
+                self._promote_deferred()
+                self._open_sessions()
+            if self._sessions:
+                name = self._schedule()
+                sess = self._sessions[name]
+                try:
+                    sess.tick()
+                except Exception as exc:  # noqa: BLE001 — §16.4 degradation
+                    if self._resolve_layout(sess.art) == self._base_layout():
+                        raise  # nothing to fall back to; stay loud (§15.3)
+                    self._handle_session_fault(name, sess, exc)
+                else:
+                    self.stats["ticks"] += 1
+                    if (self._last_scheduled not in (None, name)
+                            and len(self._sessions) > 1):
+                        self.stats["session_switches"] += 1
+                    self._last_scheduled = name
+                    if sess.idle:
+                        self._close_session(name)
+            done, self._completed = self._completed, []
         return done
 
     def run(self) -> dict[int, BfsResult]:

@@ -12,6 +12,7 @@ because ``jax.default_backend()`` still reports the CPU.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +30,12 @@ TAU, SIGMA, KW = 128, 8, 1
 N_EXT = (1 << 20) + 8   # visited rows: n_pad + sigma
 N_SETS = (1 << 17) + 1  # slice sets + the sentinel set
 BUCKET = 4096           # a queued-level bucket of active VSSs
+# the name each serve-path kernel carries in the compiled program, which a
+# device trace names its op by (the queued pull is ``pull_ms_packed``'s
+# kernel, run on the gathered queued VSSs)
+KERNEL_NAMES = {"pull_scatter_ms_packed": "pull_scatter_ms_packed",
+                "pull_ms_packed_queued": "pull_ms_packed",
+                "scatter_or": "scatter_or"}
 
 
 @pytest.fixture(scope="module")
@@ -112,6 +119,9 @@ def _cases(spec):
     "frontier_sweep"])
 def test_kernel_compiles_for_v5e(spec, kernel):
     fn, args = _cases(spec)[kernel]
-    compiled = jax.jit(fn).lower(*args).compile()
+    text = jax.jit(fn).lower(*args).compile().as_text()
     # the Mosaic kernel is in the program (not the interpreter's XLA ops)
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "tpu_custom_call" in text
+    if kernel in KERNEL_NAMES:
+        assert re.search(rf"%{KERNEL_NAMES[kernel]}(\.\d+)? = [^\n]*"
+                         r"custom-call\(", text), kernel
