@@ -1,9 +1,10 @@
-"""Binary-MMA pull layout vs the fused gather pull+scatter on dense
+"""Binary-MMA pull layout vs the packed selective-OR pull on dense
 levels (DESIGN.md §13.5).
 
 Dense serve levels have two kernel formulations: the packed layout's
-fused selective-OR pull+scatter (``kernels/pull_scatter_ms_packed.py``,
-one grid pass walking every VSS block) and the blocked bit-matrix product
+selective-OR pull (``kernels/pull_ms_packed.py``, one grid pass walking
+every VSS block, its marks ORed into their rows by the slot-table
+gather of ``kernels/gather_or.py``) and the blocked bit-matrix product
 (``kernels/pull_mma_ms_packed.py``), which unpacks the VSS bit-tiles to
 int8 planes once at tile prep and turns each dense sweep into MXU-shaped
 ``(block, tau, sigma) x (block, sigma, kappa)`` batched matmuls.  On CPU
@@ -17,7 +18,7 @@ MXU.
 This module serves kappa-sized request bursts over scale-free (kron) and
 uniform (urand) graphs at kappa ∈ {32, 64}, switching off (every level
 dense — the regime under comparison), through three engine layouts:
-``packed`` (fused gather baseline), ``mma`` (the new layout), and
+``packed`` (selective-OR baseline), ``mma`` (the new layout), and
 ``byteplane`` (the AND-OR base substrate, context for the §13.4 probe
 verdict).  Every result of every configuration is checked bit-identical
 to the CPU oracle before its row prints.

@@ -56,6 +56,35 @@ def _pull_blocked_kernel(masks_ref, ft_ref, out_ref, *, sigma, kw):
 
 
 @functools.partial(jax.jit, static_argnames=("sigma", "interpret"))
+def pull_ms_packed_lanes(
+    masks: jax.Array,      # (N_q, tau) uint8
+    f_packed: jax.Array,   # (num_sets, sigma, kw) uint32 frontier words
+    v2r: jax.Array,        # (N_q,) int32
+    *,
+    sigma: int = 8,
+    interpret: bool = False,
+) -> jax.Array:
+    """marks as the kernel writes them: (N_blk, kw*tau) int32 with word
+    ``w`` of slot ``(q, j)`` at ``[q, w*tau + j]``.  Rows from N_q up to
+    N_blk (a block multiple) are zero padding."""
+    n_q, tau = masks.shape
+    kw = f_packed.shape[2]
+    assert f_packed.shape[1] == sigma
+    blk, (masks, ft) = pad_blocks(n_q, masks, frontier_tiles(f_packed, v2r))
+    n_blk = masks.shape[0]
+    return pl.pallas_call(
+        functools.partial(_pull_blocked_kernel, sigma=sigma, kw=kw),
+        grid=(n_blk // blk,),
+        in_specs=[pl.BlockSpec((blk, tau), lambda i: (i, 0)),
+                  pl.BlockSpec((blk, kw * sigma), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((blk, kw * tau), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_blk, kw * tau), jnp.int32),
+        interpret=interpret,
+        name="pull_ms_packed",
+    )(masks, ft)
+
+
+@functools.partial(jax.jit, static_argnames=("sigma", "interpret"))
 def pull_ms_packed(
     masks: jax.Array,      # (N_q, tau) uint8
     f_packed: jax.Array,   # (num_sets, sigma, kw) uint32 frontier words
@@ -67,20 +96,18 @@ def pull_ms_packed(
     """marks (N_q, tau, kw) uint32 — packed pull for queued VSSs."""
     n_q, tau = masks.shape
     kw = f_packed.shape[2]
-    assert f_packed.shape[1] == sigma
-    blk, (masks, ft) = pad_blocks(n_q, masks, frontier_tiles(f_packed, v2r))
-    n_blk = masks.shape[0]
-    out = pl.pallas_call(
-        functools.partial(_pull_blocked_kernel, sigma=sigma, kw=kw),
-        grid=(n_blk // blk,),
-        in_specs=[pl.BlockSpec((blk, tau), lambda i: (i, 0)),
-                  pl.BlockSpec((blk, kw * sigma), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((blk, kw * tau), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_blk, kw * tau), jnp.int32),
-        interpret=interpret,
-    )(masks, ft)
+    out = pull_ms_packed_lanes(masks, f_packed, v2r, sigma=sigma,
+                               interpret=interpret)
     out = out[:n_q].reshape(n_q, kw, tau).transpose(0, 2, 1)
     return jax.lax.bitcast_convert_type(out, jnp.uint32)
+
+
+def lanes_of(marks: jax.Array) -> jax.Array:
+    """(N_q, tau, kw) uint32 marks -> the lane-dense (N_q, kw*tau) int32
+    layout of :func:`pull_ms_packed_lanes`."""
+    n_q = marks.shape[0]
+    out = jax.lax.bitcast_convert_type(marks, jnp.int32)
+    return out.transpose(0, 2, 1).reshape(n_q, -1)
 
 
 def pull_ms_packed_ref(masks, f_tiles, sigma: int = 8):

@@ -1,4 +1,10 @@
-"""Fused packed pull + scatter-OR — the megatick level step (DESIGN.md §11.2).
+"""Fused packed pull + scatter-OR (DESIGN.md §11.2).
+
+No engine path runs this kernel any more: the packed dense level gathers
+over a static slot table instead (``kernels/gather_or.py``), about three
+times faster on a v5e because this kernel's scalar scatter loop walks
+every slot.  Its jnp twin stays the reference of the dense level and the
+row-sharded mesh runner's pull.
 
 The dense packed level was two kernels with an HBM round-trip between them:
 ``pull_ms_packed`` materializes ``marks (N_q, tau, kw)`` uint32, then
@@ -16,9 +22,7 @@ moves them to SMEM, and ORs each nonzero mark word into
 execute sequentially on a core, so duplicate destination rows
 read-modify-write in a well-defined order.
 
-The jnp twin composes the two kernels' references bit-for-bit; it is the
-CPU path of the serve engine's packed substrate (and the oracle in
-tests/test_megatick.py).
+The jnp twin composes the two kernels' references bit-for-bit.
 """
 from __future__ import annotations
 

@@ -27,12 +27,13 @@ Lane substrates
 Three bit-for-bit equivalent lane layouts implement the level step:
 
 * ``layout='packed'`` — the paper-faithful kappa-bit packed words
-  (``(n_ext, kappa/32)`` uint32) driven by the fused
-  ``kernels/pull_scatter_ms_packed.py`` Pallas kernel for dense levels
-  (marks ORed straight into the visited words, DESIGN.md §11.2) and
-  ``kernels/pull_ms_packed_queued.py`` + ``kernels/scatter_or.py`` for
-  queued ones (or their jnp references when ``use_pallas=False``).  1/8
-  the state traffic; the TPU path.
+  (``(n_ext, kappa/32)`` uint32).  Dense levels run the
+  ``kernels/pull_ms_packed.py`` Pallas pull and OR its marks into their
+  rows with a gather over a static slot table (``kernels/gather_or.py``,
+  DESIGN.md §11.2); queued levels run
+  ``kernels/pull_ms_packed_queued.py`` + ``kernels/scatter_or.py`` (or
+  their jnp references when ``use_pallas=False``).  1/8 the state
+  traffic; the TPU path.
 * ``layout='byteplane'`` — ``(n_ext, kappa)`` uint8 byte-planes using the
   XLA-native scatter-max OR (``core/msbfs.py`` mechanics), slice-compacted
   to the static nonzero-mask slot list on the jnp path (§11.2).  The fast
@@ -143,8 +144,8 @@ Megatick traversal (DESIGN.md §11)
 ----------------------------------
 ``BfsEngine(megatick=T)`` with ``T > 1`` moves the per-graph level loop
 on-device: up to ``T`` consecutive dense levels run inside one
-``jax.lax.while_loop`` dispatch (pull+scatter via the fused
-``kernels/pull_scatter_ms_packed.py`` on the packed substrate, diff, level
+``jax.lax.while_loop`` dispatch (pull + slot-table gather-OR on the
+packed substrate, diff, level
 stamps, per-lane reach, the Eq. (6) decision, and per-lane done flags all
 stay resident), returning to host only when every active lane has
 finished, when the policy picks a queued level (executed host-side with
@@ -182,10 +183,11 @@ from repro.core.graph import Graph
 from repro.core.msbfs_packed import frontier_planes, unpack_levels_check
 from repro.kernels import ops
 from repro.kernels import pull_mma_ms_packed as mma_mod
+from repro.kernels.gather_or import gather_or, slot_table
+from repro.kernels.pull_ms_packed import (
+    lanes_of, pull_ms_packed_lanes, pull_ms_packed_ref)
 from repro.kernels.pull_ms_packed_queued import (
     pull_ms_packed_queued, pull_ms_packed_queued_ref)
-from repro.kernels.pull_scatter_ms_packed import (
-    pull_scatter_ms_packed, pull_scatter_ms_packed_ref)
 from repro.kernels.scatter_or import scatter_or, scatter_or_ref
 from repro.serve import lifecycle as lifecycle_mod
 from repro.serve import mesh as mesh_mod
@@ -1078,6 +1080,9 @@ class _GraphOperands(NamedTuple):
     nz_mask: jax.Array | None
     nz_parent: jax.Array | None
     nz_rows: jax.Array | None
+    # the packed dense level's destination-major slot table (§11.2)
+    chunks: jax.Array | None
+    chunk_rows: jax.Array | None
 
 
 class _LaneRunner:
@@ -1148,7 +1153,15 @@ class _LaneRunner:
             nz = (jnp.asarray(mask_c), jnp.asarray(parent_c.astype(np.int32)),
                   jnp.asarray(rows_c.astype(np.int32)))
             self._pad_slice = int(mask_c.size - 1)  # the sentinel entry
-        self._ops = _GraphOperands(bd, self._tiles, *nz)
+        # the packed dense level ORs marks into rows through a gather over
+        # a static slot table (§11.2); entries gathered a level, for stats
+        table, self.dense_gathered = (None, None), 0
+        if self.substrate == "packed" and not self._mma:
+            t = slot_table(np.asarray(bd.row_ids), np.asarray(bd.masks),
+                           bd.n_ext, self.kw)
+            table, self.dense_gathered = (
+                (jnp.asarray(t.chunks), jnp.asarray(t.rows)), t.entries)
+        self._ops = _GraphOperands(bd, self._tiles, *nz, *table)
         # megatick residency (DESIGN.md §11.1): the bucket-guard threshold
         # (smallest |Q| whose padded bucket reaches the full sweep), and
         # jitted drivers per (T, policy) pair
@@ -1230,16 +1243,15 @@ class _LaneRunner:
                     interpret=self._interpret)
             return mma_mod.pull_scatter_mma_ms_packed_ref(
                 v, t.a_planes, f, t.v2r, t.rows)
-        # fused pull+scatter (DESIGN.md §11.2): marks are computed in
-        # registers and ORed straight into the visited words — no
-        # (N_q*tau, kw) marks array between the pull and the scatter
-        rows_flat = bd.row_ids.reshape(-1)
+        # the dense pull's lane-dense marks, ORed into their rows by the
+        # destination-major gather over the static slot table (§11.2)
         if self.use_pallas:
-            return pull_scatter_ms_packed(v, bd.masks, f, bd.v2r, rows_flat,
-                                          sigma=bd.sigma,
-                                          interpret=self._interpret)
-        return pull_scatter_ms_packed_ref(v, bd.masks, f, bd.v2r, rows_flat,
-                                          sigma=bd.sigma)
+            marks = pull_ms_packed_lanes(bd.masks, f, bd.v2r, sigma=bd.sigma,
+                                         interpret=self._interpret)
+        else:
+            marks = lanes_of(pull_ms_packed_ref(bd.masks, f[bd.v2r],
+                                                sigma=bd.sigma))
+        return v | gather_or(marks, g.chunks, g.chunk_rows, self.kw)
 
     def _pull_scatter_queued(self, g: "_GraphOperands", v, f, qids):
         """Frontier-compacted pull+scatter over the active list only
@@ -1719,6 +1731,8 @@ class _GraphSession:
         (``dense``, ``queued`` or ``megatick``), None with no lane."""
         eng = self.engine
         runner, art, kappa = self.runner, self.art, eng.kappa
+        # the sharded runner keeps no slot table
+        gathered = getattr(runner, "dense_gathered", 0)
         queue, lanes = self.queue, self.lanes
         span = eng._spans
         with span("serve.admit"):
@@ -1787,6 +1801,7 @@ class _GraphSession:
                 eng.stats["megaticks"] += 1
                 eng.stats["levels"] += ticks
                 eng.stats["levels_dense"] += ticks
+                eng.stats["dense_gathered"] += ticks * gathered
                 w = hist[:ticks].astype(np.int64)
                 ells = self.ell + 1 + np.arange(ticks, dtype=np.int64)
                 self.reach_host += w.sum(axis=0)
@@ -1852,6 +1867,7 @@ class _GraphSession:
             with span("serve.dispatch"):
                 self.state, new_lane = runner.level(self.state, self.ell)
             eng.stats["levels_dense"] += 1
+            eng.stats["dense_gathered"] += gathered
         eng.stats["levels"] += 1
         eng.stats["dispatches"] += 1
         nl = self._read("new_lane", np.asarray, new_lane)
@@ -2219,6 +2235,8 @@ class BfsEngine:
             # device programs launched while serving; the active VSSs of
             # queued levels and the bucket rows dispatched for them
             "dispatches": 0, "queued_vss": 0, "queued_rows": 0,
+            # slot-table entries the packed dense levels gathered (§11.2)
+            "dense_gathered": 0,
             **{"syncs:" + site: 0 for site in SYNC_SITES},
         }
         self._spans = spans_mod.Spans(self.stats, SPAN_NAMES)

@@ -131,8 +131,9 @@ def test_megatick_closeness(duo):
 
 
 def test_megatick_pallas_packed_path():
-    """The fused pull+scatter kernel (interpret mode) inside the while_loop
-    driver: packed substrate, megatick=4, oracle-exact."""
+    """The dense level's Pallas pull (interpret mode) and slot-table
+    gather-OR inside the while_loop window: packed substrate, megatick=4,
+    oracle-exact, every windowed level counted in ``dense_gathered``."""
     g = graphs.make("road", scale=5, seed=0)
     eng = BfsEngine(kappa=32, layout="packed", use_pallas=True,
                     switching="off", megatick=4)
@@ -140,6 +141,8 @@ def test_megatick_pallas_packed_path():
     rids = {eng.submit("tiny", s): s for s in (0, 7, g.n - 1)}
     res = eng.run()
     assert eng.stats["megaticks"] > 0
+    assert eng.stats["dense_gathered"] == (
+        eng.stats["levels_dense"] * eng._runners["tiny"].dense_gathered) > 0
     for rid, s in rids.items():
         assert (res[rid].levels == ref_bfs.bfs_levels(g, s)).all()
 

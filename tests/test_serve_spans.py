@@ -19,7 +19,7 @@ from repro.data import graphs
 from repro.serve.bfs_engine import (
     SPAN_NAMES, SYNC_SITES, BfsEngine, _LaneRunner, build_artifacts)
 
-COUNTERS = ("dispatches", "queued_vss", "queued_rows")
+COUNTERS = ("dispatches", "queued_vss", "queued_rows", "dense_gathered")
 
 
 def _engine():
@@ -113,6 +113,16 @@ def test_queued_rows_are_the_padded_buckets(served):
             continue
         assert rows >= vss
         assert rows < 2 * vss or rows == VSS_PAD
+
+
+def test_dense_gathered_counts_every_dense_level(served):
+    """Each dense level adds the runner's slot-table entries, host-side."""
+    eng, _, snaps, _ = served
+    per_level = eng._runners["g"].dense_gathered
+    assert per_level > 0
+    for s0, s1 in zip(snaps, snaps[1:]):
+        dense = s1["levels_dense"] - s0["levels_dense"]
+        assert s1["dense_gathered"] - s0["dense_gathered"] == dense * per_level
 
 
 def _events(xplane):

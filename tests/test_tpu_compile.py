@@ -21,6 +21,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
 from repro.kernels import pull_mma_ms_packed as mma
+from repro.kernels.gather_or import gather_or
+from repro.kernels.pull_ms_packed import pull_ms_packed_lanes
 from repro.kernels.pull_ms_packed_queued import pull_ms_packed_queued
 from repro.kernels.pull_scatter_ms_packed import pull_scatter_ms_packed
 from repro.kernels.scatter_or import scatter_or
@@ -30,10 +32,12 @@ TAU, SIGMA, KW = 128, 8, 1
 N_EXT = (1 << 20) + 8   # visited rows: n_pad + sigma
 N_SETS = (1 << 17) + 1  # slice sets + the sentinel set
 BUCKET = 4096           # a queued-level bucket of active VSSs
+CHUNKS, D, C = 2_300_000, 8, 8   # the dense level's slot table (§11.2)
 # the name each serve-path kernel carries in the compiled program, which a
 # device trace names its op by (the queued pull is ``pull_ms_packed``'s
 # kernel, run on the gathered queued VSSs)
 KERNEL_NAMES = {"pull_scatter_ms_packed": "pull_scatter_ms_packed",
+                "dense_gather_or": "pull_ms_packed",
                 "pull_ms_packed_queued": "pull_ms_packed",
                 "scatter_or": "scatter_or"}
 
@@ -84,6 +88,13 @@ def _cases(spec):
             lambda *a: pull_scatter_ms_packed(*a, sigma=SIGMA,
                                               interpret=False),
             (v, masks, f, v2r, rows)),
+        # the packed dense level: the pull, then the slot-table gather-OR
+        "dense_gather_or": (
+            lambda v, m, f, v2r, chunks, rows: v | gather_or(
+                pull_ms_packed_lanes(m, f, v2r, sigma=SIGMA,
+                                     interpret=False), chunks, rows, KW),
+            (v, masks, f, v2r, spec((D, CHUNKS), jnp.int32),
+             spec((C, N_EXT), jnp.int32))),
         "pull_ms_packed_queued": (
             lambda *a: pull_ms_packed_queued(*a, sigma=SIGMA,
                                              interpret=False),
@@ -114,8 +125,8 @@ def _cases(spec):
 
 
 @pytest.mark.parametrize("kernel", [
-    "pull_scatter_ms_packed", "pull_ms_packed_queued", "scatter_or",
-    "pull_mma_ms_packed", "pull_scatter_mma_ms_packed", "pull_ss_packed",
+    "pull_scatter_ms_packed", "dense_gather_or", "pull_ms_packed_queued",
+    "scatter_or", "pull_mma_ms_packed", "pull_scatter_mma_ms_packed", "pull_ss_packed",
     "frontier_sweep"])
 def test_kernel_compiles_for_v5e(spec, kernel):
     fn, args = _cases(spec)[kernel]
