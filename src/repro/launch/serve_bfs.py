@@ -182,9 +182,10 @@ def main():
                          "row-sharded graph-parallel artifacts for graphs "
                          "over --device-budget-mb")
     ap.add_argument("--devices", type=int, default=None,
-                    help="devices in the mesh (default: all visible); "
-                         "use XLA_FLAGS=--xla_force_host_platform_device_"
-                         "count=8 for virtual CPU devices")
+                    help="devices in the mesh, the first N visible "
+                         "(default: all visible; BfsEngine(mesh=N) takes "
+                         "the same); use XLA_FLAGS=--xla_force_host_"
+                         "platform_device_count=8 for virtual CPU devices")
     ap.add_argument("--device-budget-mb", type=float, default=None,
                     help="per-device artifact byte budget in MiB (§17.2): "
                          "graphs projected over it build row-sharded "
@@ -242,13 +243,11 @@ def main():
 
         from repro.serve.mesh import EngineMesh
 
-        devs = jax.devices()
-        if args.devices is not None:
-            if not 1 <= args.devices <= len(devs):
-                ap.error(f"--devices must be in [1, {len(devs)}], "
-                         f"got {args.devices}")
-            devs = devs[:args.devices]
-        mesh = EngineMesh(devs)
+        n_devs = jax.device_count()
+        if args.devices is not None and not 1 <= args.devices <= n_devs:
+            ap.error(f"--devices must be in [1, {n_devs}], "
+                     f"got {args.devices}")
+        mesh = EngineMesh.of(args.devices or n_devs)
         print(f"mesh: {mesh}")
     elif args.devices is not None:
         ap.error("--devices requires --mesh")

@@ -209,9 +209,10 @@ SYNC_SITES = {"active_mask": True, "new_lane": False, "megatick": False,
               "watch": True, "gather_cols": True}
 # the host spans of the serving path (serve/spans.py); their self seconds
 # are ``stats["host_s:<name>"]``
-SPAN_NAMES = ("serve.step", "serve.housekeep", "serve.tick", "serve.admit",
-              "serve.decide", "serve.expand", "serve.dispatch", "serve.hooks",
-              "serve.finish", *("serve.sync." + s for s in SYNC_SITES))
+SPAN_NAMES = ("serve.step", "serve.housekeep", "serve.round", "serve.tick",
+              "serve.admit", "serve.decide", "serve.expand", "serve.dispatch",
+              "serve.hooks", "serve.finish",
+              *("serve.sync." + s for s in SYNC_SITES))
 
 
 # ---------------------------------------------------------------------------
@@ -1093,11 +1094,20 @@ class _LaneRunner:
     bookkeeping; the reseed step clears a set of lanes and seeds new sources
     into them without touching the other lanes' bits (bitwise lane
     independence makes this exact, not approximate).
+
+    A replica's runner (§17.1) is given its chip as ``device``: every array
+    it makes (slot table, slice list, tiles, empty state) is committed
+    there, and its per-call operands are left on the host for jit to place
+    beside them, so no level reads an operand across chips.  ``table`` is
+    the graph's host slot table where one was built already (the replicas
+    of a graph share one, ``self.table``).  A lone runner (``device=None``)
+    keeps its arrays on the default device, uncommitted.
     """
 
     def __init__(self, bd: BvssDevice, kappa: int, *, layout: str = "auto",
                  use_pallas: bool | None = None,
-                 mma_tiles: mma_mod.MmaTiles | None = None):
+                 mma_tiles: mma_mod.MmaTiles | None = None,
+                 device=None, table=None):
         if kappa % 32 != 0:
             raise ValueError("kappa must be a multiple of 32 (packed words)")
         if layout == "auto":
@@ -1110,6 +1120,7 @@ class _LaneRunner:
         self.kappa = kappa
         self.kw = kappa // 32
         self.layout = layout
+        self.device = device
         # the MMA layout changes only the *dense pull* (DESIGN.md §13.2);
         # state, reseed, and queued sweeps follow the substrate — packed
         # words when Pallas kernels drive the fused MMA scatter, the
@@ -1119,6 +1130,8 @@ class _LaneRunner:
                           if self._mma else layout)
         self._tiles = (mma_tiles if mma_tiles is not None
                        else mma_mod.prep_mma_tiles(bd)) if self._mma else None
+        if self._tiles is not None and device is not None:
+            self._tiles = jax.device_put(self._tiles, device)
         self.use_pallas = use_pallas
         self._interpret = jax.default_backend() != "tpu"
         self._level_fn = jax.jit(self._level)
@@ -1150,18 +1163,21 @@ class _LaneRunner:
             parent_c = np.append(np.asarray(bd.v2r)[nz_vss], bd.num_sets)
             rows_c = np.append(np.asarray(bd.row_ids)[nz_vss, nz_slot],
                                bd.n_pad)
-            nz = (jnp.asarray(mask_c), jnp.asarray(parent_c.astype(np.int32)),
-                  jnp.asarray(rows_c.astype(np.int32)))
+            nz = (self.put(mask_c), self.put(parent_c.astype(np.int32)),
+                  self.put(rows_c.astype(np.int32)))
             self._pad_slice = int(mask_c.size - 1)  # the sentinel entry
         # the packed dense level ORs marks into rows through a gather over
         # a static slot table (§11.2); entries gathered a level, for stats
-        table, self.dense_gathered = (None, None), 0
+        self.table, self.dense_gathered = None, 0
+        dev_table = (None, None)
         if self.substrate == "packed" and not self._mma:
-            t = slot_table(np.asarray(bd.row_ids), np.asarray(bd.masks),
-                           bd.n_ext, self.kw)
-            table, self.dense_gathered = (
-                (jnp.asarray(t.chunks), jnp.asarray(t.rows)), t.entries)
-        self._ops = _GraphOperands(bd, self._tiles, *nz, *table)
+            self.table = table if table is not None else slot_table(
+                np.asarray(bd.row_ids), np.asarray(bd.masks), bd.n_ext,
+                self.kw)
+            dev_table = (self.put(self.table.chunks),
+                         self.put(self.table.rows))
+            self.dense_gathered = self.table.entries
+        self._ops = _GraphOperands(bd, self._tiles, *nz, *dev_table)
         # megatick residency (DESIGN.md §11.1): the bucket-guard threshold
         # (smallest |Q| whose padded bucket reaches the full sweep), and
         # jitted drivers per (T, policy) pair
@@ -1171,7 +1187,8 @@ class _LaneRunner:
             self._dense_guard = (1 << (bd.num_vss_pad - 1).bit_length()) // 2 + 1
         self._megatick_fns: dict[tuple[int, bool, float], object] = {}
         self._init_state: LaneState | None = None
-        self._reach_zero = jnp.zeros(kappa, jnp.int32)  # policy-off filler
+        self._reach_zero = jnp.zeros(kappa, jnp.int32,  # policy-off filler
+                                     device=device)
         # extraction gather: slice the finished lanes' level columns on
         # device before the host copy; re-traced per power-of-two bucket of
         # len(done), so at most log2(kappa)+1 shapes ever compile
@@ -1182,21 +1199,38 @@ class _LaneRunner:
         self._watch_fn = jax.jit(
             lambda levels, ids: levels[ids, jnp.arange(kappa)])
 
+    # ---- placement ----------------------------------------------------------
+    def put(self, x) -> jax.Array:
+        """A host array as a device array of this runner: committed to its
+        chip, or on the default device for a lone runner."""
+        if self.device is None:
+            return jnp.asarray(x)
+        return jax.device_put(x, self.device)
+
+    def _arg(self, x, dtype):
+        """One per-call operand.  A replica's runner leaves a host value on
+        the host (jit copies it to the chip of the committed operands); a
+        lone runner's becomes an array on the default device."""
+        if self.device is None:
+            return jnp.asarray(x, dtype)
+        return x if isinstance(x, jax.Array) else np.asarray(x, dtype)
+
     # ---- state ------------------------------------------------------------
     def init_state(self) -> LaneState:
         """The all-empty lane state.  Immutable device arrays, so the one
         instance is built lazily and shared by every batch session (a fresh
         build per drain was measurable host overhead)."""
         if self._init_state is None:
-            bd, kappa = self.bd, self.kappa
+            bd, kappa, dev = self.bd, self.kappa, self.device
             if self.substrate == "packed":
-                v = jnp.zeros((bd.n_ext, self.kw), jnp.uint32)
+                v = jnp.zeros((bd.n_ext, self.kw), jnp.uint32, device=dev)
             else:
-                v = jnp.zeros((bd.n_ext, kappa), jnp.uint8)
+                v = jnp.zeros((bd.n_ext, kappa), jnp.uint8, device=dev)
             self._init_state = LaneState(
                 v=v,
                 f=self._planes(v),
-                levels=jnp.full((bd.n_ext, kappa), UNREACHED, jnp.int32),
+                levels=jnp.full((bd.n_ext, kappa), UNREACHED, jnp.int32,
+                                device=dev),
             )
         return self._init_state
 
@@ -1327,11 +1361,12 @@ class _LaneRunner:
         return self._finish_level(state, v_next, ell)
 
     def level(self, state: LaneState, ell: int):
-        return self._level_fn(self._ops, state, jnp.int32(ell))
+        return self._level_fn(self._ops, state, self._arg(ell, np.int32))
 
     def level_queued(self, state: LaneState, ell: int, qids: np.ndarray):
-        return self._level_queued_fn(self._ops, state, jnp.int32(ell),
-                                     jnp.asarray(qids, jnp.int32))
+        return self._level_queued_fn(self._ops, state,
+                                     self._arg(ell, np.int32),
+                                     self._arg(qids, np.int32))
 
     def active_set_mask(self, f) -> np.ndarray:
         """Union frontier across lanes -> (num_sets,) bool on host.
@@ -1409,11 +1444,11 @@ class _LaneRunner:
                 self._megatick, T=int(ticks), policy_on=bool(policy_on),
                 eta=float(eta)))
             self._megatick_fns[key] = fn
-        reach_dev = (jnp.asarray(reach, jnp.int32) if policy_on
+        reach_dev = (self._arg(reach, np.int32) if policy_on
                      else self._reach_zero)
-        return fn(self._ops, state, reach_dev, jnp.int32(ell0),
-                  jnp.asarray(active, bool),
-                  jnp.asarray(admitted_at, jnp.int32))
+        return fn(self._ops, state, reach_dev, self._arg(ell0, np.int32),
+                  self._arg(active, bool),
+                  self._arg(admitted_at, np.int32))
 
     def _megatick(self, g: "_GraphOperands", state: LaneState, reach, ell0,
                   active, admitted_at, *, T: int, policy_on: bool,
@@ -1482,7 +1517,8 @@ class _LaneRunner:
         b = min(self.kappa, 1 << (len(cols) - 1).bit_length())
         idx = np.full(b, cols[0], np.int32)
         idx[: len(cols)] = cols
-        out = np.asarray(self._gather_cols_fn(levels, jnp.asarray(idx)))
+        out = np.asarray(self._gather_cols_fn(levels,
+                                              self._arg(idx, np.int32)))
         return out[:, : len(cols)]
 
     # ---- clear + seed a subset of lanes -----------------------------------
@@ -1524,9 +1560,9 @@ class _LaneRunner:
 
     def reseed(self, state: LaneState, clear: np.ndarray, new_src: np.ndarray,
                ell: int) -> LaneState:
-        return self._reseed_fn(state, jnp.asarray(clear, bool),
-                               jnp.asarray(new_src, jnp.int32),
-                               jnp.int32(ell))
+        return self._reseed_fn(state, self._arg(clear, bool),
+                               self._arg(new_src, np.int32),
+                               self._arg(ell, np.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -1604,14 +1640,17 @@ class _GraphSession:
     """
 
     def __init__(self, engine: "BfsEngine", name: str,
-                 queue: "_TenantQueue", art: GraphArtifacts, runner=None):
+                 queue: "_TenantQueue", art: GraphArtifacts, runner=None,
+                 replica: int = 0):
         self.engine = engine
         self.name = name
         self.queue = queue
         self.art = art
-        # §17.1: a mesh session group hands each replica its own runner;
-        # single-device sessions resolve through the engine as before
+        # §17.1: a mesh session group hands each replica its own runner
+        # and its index in the group; single-device sessions resolve
+        # through the engine as before, and are replica 0
         self.runner = runner if runner is not None else engine._runner_for(art)
+        self._levels_key = f"levels:replica{replica}"
         kappa = engine.kappa
         self.lanes: list[BfsQuery | None] = [None] * kappa
         self.wl: list[Workload | None] = [None] * kappa
@@ -1721,14 +1760,26 @@ class _GraphSession:
     # ---- one scheduling tick ----------------------------------------------
     def tick(self) -> None:
         """One level or one megatick window for every lane, as the span
-        ``serve.tick`` (its arguments: graph, level, mode)."""
+        ``serve.tick`` (its arguments: graph, level, mode): the issue half,
+        then the collect half of the level it put in flight."""
         with self.engine._spans("serve.tick", graph=self.name,
                                 level=self.ell) as ann:
-            ann.set_metadata(mode=self._tick())
+            mode, new_lane = self.issue()
+            if new_lane is not None:
+                self.collect(new_lane)
+            ann.set_metadata(mode=mode)
 
-    def _tick(self) -> str | None:
-        """The body of :meth:`tick`; returns the mode of its last level
-        (``dense``, ``queued`` or ``megatick``), None with no lane."""
+    def _count_levels(self, n: int) -> None:
+        self.engine.stats["levels"] += n
+        self.engine.stats[self._levels_key] += n
+
+    def issue(self) -> tuple[str | None, jax.Array | None]:
+        """Admission, the mode decision, the queued expansion and the
+        level's dispatch: everything up to the level's result, which stays
+        in flight.  Returns the mode of the level (``dense``, ``queued`` or
+        ``megatick``; None with no lane) and its per-lane new counts for
+        :meth:`collect`, or None where nothing is left to collect.  A
+        megatick window runs whole in here, read-backs and all (§17.1)."""
         eng = self.engine
         runner, art, kappa = self.runner, self.art, eng.kappa
         # the sharded runner keeps no slot table
@@ -1776,7 +1827,7 @@ class _GraphSession:
                                            self.ell)
                 eng.stats["dispatches"] += 1
         if all(q is None for q in lanes):
-            return None
+            return None, None
         active_arr = np.fromiter((q is not None for q in lanes), bool, kappa)
         # ---- megatick window: up to T fused dense levels (§11.1) ---------
         # windows run when this graph's queue is drained; under backlog
@@ -1785,8 +1836,8 @@ class _GraphSession:
         # that still pay the window overhead)
         if eng.megatick > 1 and not queue and not self.prefer_host:
             if self.meta_dev is None:
-                self.meta_dev = (jnp.asarray(active_arr),
-                                 jnp.asarray(self.admitted_at, jnp.int32))
+                self.meta_dev = (runner.put(active_arr),
+                                 runner.put(self.admitted_at))
             with span("serve.dispatch"):
                 self.state, hist = runner.megatick(
                     self.state, self.reach_host.astype(np.int32), self.ell,
@@ -1799,7 +1850,7 @@ class _GraphSession:
             ticks = int((hist[:, 0] >= 0).sum())
             if ticks:
                 eng.stats["megaticks"] += 1
-                eng.stats["levels"] += ticks
+                self._count_levels(ticks)
                 eng.stats["levels_dense"] += ticks
                 eng.stats["dense_gathered"] += ticks * gathered
                 w = hist[:ticks].astype(np.int64)
@@ -1815,9 +1866,10 @@ class _GraphSession:
                 # window
                 if self._finish_tick(hist[ticks - 1], tl):
                     self.meta_dev = None
-                    return "megatick"  # freed lanes: admit first
+                    return "megatick", None  # freed lanes: admit first
                 if ticks == eng.megatick:
-                    return "megatick"  # window exhausted, every lane active
+                    # window exhausted, every lane active
+                    return "megatick", None
             # the window stopped short of T with no lane finished: the
             # on-device Eq. (6) verdict was queued — run that one level
             # host-side with the §10 bucketed machinery, and stay on
@@ -1868,8 +1920,14 @@ class _GraphSession:
                 self.state, new_lane = runner.level(self.state, self.ell)
             eng.stats["levels_dense"] += 1
             eng.stats["dense_gathered"] += gathered
-        eng.stats["levels"] += 1
+        self._count_levels(1)
         eng.stats["dispatches"] += 1
+        return mode, new_lane
+
+    def collect(self, new_lane: jax.Array) -> None:
+        """Wait for the level :meth:`issue` put in flight, and fold its
+        per-lane new counts in: the host mirrors, the workload hooks, the
+        watched targets, and the extraction of every finished lane."""
         nl = self._read("new_lane", np.asarray, new_lane)
         self.reach_host += nl
         self.far64 += (self.ell - self.admitted_at).astype(np.int64) * nl
@@ -1878,7 +1936,6 @@ class _GraphSession:
         tl = self._watch_tick()
         if self._finish_tick(nl, tl):
             self.meta_dev = None
-        return mode
 
     # ---- per-level workload hooks (§12.3) ---------------------------------
     def _run_hooks(self, counts: np.ndarray, ells: np.ndarray) -> None:
@@ -1907,7 +1964,7 @@ class _GraphSession:
                               self.engine.kappa)).any():
             return None
         if self.watch_dev is None:
-            self.watch_dev = jnp.asarray(
+            self.watch_dev = self.runner.put(
                 np.maximum(self.watch_ids, 0).astype(np.int32))
         self.tl = self._read("watch", self.runner.watch_levels,
                              self.state.levels, self.watch_dev)
@@ -2085,7 +2142,7 @@ class BfsEngine:
                  build_retries: int = 0,
                  build_backoff: float = 0.05,
                  build_backoff_cap: float = 2.0,
-                 mesh: "mesh_mod.EngineMesh | None" = None,
+                 mesh: "mesh_mod.EngineMesh | int | None" = None,
                  device_budget: int | None = None):
         if kappa % 32 != 0 or kappa <= 0:
             raise ValueError("kappa must be a positive multiple of 32")
@@ -2181,7 +2238,9 @@ class BfsEngine:
         self.cache.on_evict(self._drop_runner)
         # §17 mesh serving: device groups for source-parallel replication
         # and the per-device byte bound that triggers row-sharded builds
-        # (§17.2) and per-device eviction (§17.3)
+        # (§17.2) and per-device eviction (§17.3); an int takes the first
+        # that many devices (EngineMesh.of)
+        mesh = mesh_mod.EngineMesh.of(mesh)
         self.mesh = mesh
         self.device_budget = device_budget
         self._mesh_runners: dict[str, list] = {}
@@ -2237,6 +2296,13 @@ class BfsEngine:
             "dispatches": 0, "queued_vss": 0, "queued_rows": 0,
             # slot-table entries the packed dense levels gathered (§11.2)
             "dense_gathered": 0,
+            # §17.1: the mesh's devices (0 without one), the session
+            # groups' rounds that ran a level, and the levels of each
+            # replica index of a group (a lone session is replica 0)
+            "mesh_devices": mesh.n_devices if mesh is not None else 0,
+            "rounds": 0,
+            **{f"levels:replica{k}": 0
+               for k in range(mesh.group_size if mesh is not None else 1)},
             **{"syncs:" + site: 0 for site in SYNC_SITES},
         }
         self._spans = spans_mod.Spans(self.stats, SPAN_NAMES)
@@ -2967,6 +3033,8 @@ class BfsEngine:
         return None
 
     def _runner_for(self, art: GraphArtifacts) -> _LaneRunner:
+        if art.replicas:
+            return self._mesh_runners_for(art)[0]  # §17.1: replica 0's
         name, bd = art.name, art.bd
         r = self._runners.get(name)
         if getattr(art, "sharded", None) is not None:
@@ -2988,16 +3056,23 @@ class BfsEngine:
 
     def _mesh_runners_for(self, art: GraphArtifacts) -> list[_LaneRunner]:
         """Per-replica runners for a §17.1 source-parallel artifact, one
-        per device in its placement group, cached per graph (the jit
+        per device in its placement group, each committed to its replica's
+        chip and all sharing one host slot table; cached per graph (the jit
         caches inside a runner are per-shape and expensive to rebuild)."""
         name = art.name
         group = self._mesh_runners.get(name)
         if group is None or group[0].bd is not art.replicas[0]:
             layout = self._resolve_layout(art)
-            group = [_LaneRunner(bd_k, self.kappa, layout=layout,
-                                 use_pallas=self.use_pallas,
-                                 mma_tiles=art.mma)
-                     for bd_k in art.replicas]
+            # the probe's runners (on the default device) serve no replica
+            self._probe_runners_last = None
+            group, table = [], None
+            for bd_k in art.replicas:
+                (dev,) = bd_k.row_ids.devices()
+                group.append(_LaneRunner(
+                    bd_k, self.kappa, layout=layout,
+                    use_pallas=self.use_pallas, mma_tiles=art.mma,
+                    device=dev, table=table))
+                table = group[-1].table
             self._mesh_runners[name] = group
             # keep the single-runner registry pointing at replica 0 so
             # layout introspection (tests, launchers) sees the mesh graph

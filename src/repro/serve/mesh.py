@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from typing import NamedTuple
 
 import jax
@@ -93,6 +94,21 @@ class EngineMesh:
         self.groups = tuple(tuple(self.devices[i:i + gs])
                             for i in range(0, len(self.devices), gs))
 
+    @classmethod
+    def of(cls, mesh: "EngineMesh | int | None") -> "EngineMesh | None":
+        """The engine's ``mesh`` argument as a mesh: ``None`` and an
+        ``EngineMesh`` as given, an int ``n`` as one group of the first
+        ``n`` visible devices, or of all of them where fewer are visible
+        (one device is the degenerate mesh: it serves as a single device).
+        ``launch/serve_bfs.py --mesh --devices N`` resolves through here."""
+        if mesh is None or isinstance(mesh, cls):
+            return mesh
+        if isinstance(mesh, bool) or not isinstance(mesh, int) or mesh < 1:
+            raise ValueError(
+                f"mesh must be an EngineMesh or a device count >= 1, "
+                f"got {mesh!r}")
+        return cls(jax.devices()[:mesh])
+
     @property
     def n_devices(self) -> int:
         return len(self.devices)
@@ -127,9 +143,12 @@ def projected_device_bytes(b: Bvss) -> int:
 
 
 def _replicate_bd(bd: blest.BvssDevice, device) -> blest.BvssDevice:
-    """One replica of the device substrate on ``device``; the
+    """One replica of the device substrate on ``device``: ``bd`` itself
+    where it already lives there (no second copy), else a copy whose
     masks/masks_packed aliasing (tau % 4 != 0) is preserved so the
     replica costs what the original did."""
+    if bd.row_ids.devices() == {device}:
+        return bd
     masks = jax.device_put(bd.masks, device)
     return dataclasses.replace(
         bd,
@@ -204,10 +223,12 @@ def build_mesh_artifacts(name, g, *, group=None, reorder=None, config=None,
     from repro.serve import bfs_engine as eng_mod
 
     config = config or BvssConfig()
+    t0 = time.perf_counter()
     rr = reorder_mod.reorder(g, sigma=config.sigma, force=reorder)
     gp = g.permuted(rr.perm)
     b = build_bvss(gp, config)
     projected = projected_device_bytes(b)
+    host_s = time.perf_counter() - t0  # build_s counts it, as off the mesh
 
     if device_budget is not None and projected > device_budget:
         if group is None or len(group) < 2:
@@ -215,7 +236,9 @@ def build_mesh_artifacts(name, g, *, group=None, reorder=None, config=None,
                 f"graph {name!r}: projected artifact {projected} B exceeds "
                 f"the per-device byte budget {device_budget} B and no "
                 f"device group is available to shard it over")
-        return _build_sharded(eng_mod, name, g, b, rr, group, fault_hook)
+        art = _build_sharded(eng_mod, name, g, b, rr, group, fault_hook)
+        art.build_s = time.perf_counter() - t0
+        return art
 
     kw = dict(reorder=reorder, config=config, probe=probe,
               probe_use_pallas=probe_use_pallas, probe_runner=probe_runner,
@@ -223,6 +246,7 @@ def build_mesh_artifacts(name, g, *, group=None, reorder=None, config=None,
     if eta is not None:
         kw["eta"] = eta
     art = eng_mod.build_artifacts(name, g, **kw)
+    t0 = time.perf_counter()
     if group is not None and len(group) > 1:
         replicas = []
         for k, dev in enumerate(group):
@@ -232,6 +256,7 @@ def build_mesh_artifacts(name, g, *, group=None, reorder=None, config=None,
         art.replicas = replicas
         art.placement = tuple(int(d.id) for d in group)
         art.per_device_bytes = {int(d.id): art.device_bytes for d in group}
+    art.build_s += host_s + time.perf_counter() - t0
     return art
 
 
@@ -333,6 +358,10 @@ class ShardedLaneRunner:
             self._reseed_shard,
             in_specs=(shard, repl, shard, repl, repl, repl),
             out_specs=(shard, repl, shard)))
+
+    def put(self, x) -> jax.Array:
+        """A small host array for the session (the state spans the group)."""
+        return jnp.asarray(x)
 
     # ---- state ------------------------------------------------------------
     def init_state(self) -> ShardLaneState:
@@ -552,8 +581,9 @@ class _MeshSessionGroup:
         self.queue = queue
         self.art = art
         runners = engine._mesh_runners_for(art)
-        self.replicas = [_GraphSession(engine, name, queue, art, runner=r)
-                         for r in runners]
+        self.replicas = [_GraphSession(engine, name, queue, art, runner=r,
+                                       replica=k)
+                         for k, r in enumerate(runners)]
 
     @property
     def lanes(self):
@@ -569,9 +599,29 @@ class _MeshSessionGroup:
         return sum(s.in_flight for s in self.replicas)
 
     def tick(self) -> None:
-        # admission order is deterministic (replica 0 fills first); a
-        # replica with no lanes in flight and nothing left to admit is
-        # skipped so idle replicas cost nothing per tick
-        for s in self.replicas:
-            if s.in_flight or self.queue:
-                s.tick()
+        """One round, as the span ``serve.round``: every replica's issue
+        half, in replica order, then the collect half of every level put in
+        flight, in the same order, so that the replicas' levels run on
+        their chips together.  Admission order is deterministic (replica 0
+        fills first); a replica with no lanes in flight and nothing left to
+        admit is skipped, so idle replicas cost nothing per round."""
+        eng = self.engine
+        spans, levels0 = eng._spans, eng.stats["levels"]
+        with spans("serve.round", graph=self.name) as ann:
+            issued = []
+            for k, s in enumerate(self.replicas):
+                if not (s.in_flight or self.queue):
+                    continue
+                with spans("serve.tick", graph=self.name, level=s.ell,
+                           replica=k, half="issue") as a:
+                    mode, new_lane = s.issue()
+                    a.set_metadata(mode=mode)
+                if new_lane is not None:
+                    issued.append((k, s, new_lane))
+            for k, s, new_lane in issued:
+                with spans("serve.tick", graph=self.name, level=s.ell,
+                           replica=k, half="collect"):
+                    s.collect(new_lane)
+            ann.set_metadata(replicas=len(issued))
+        if eng.stats["levels"] > levels0:
+            eng.stats["rounds"] += 1
