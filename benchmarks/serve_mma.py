@@ -2,9 +2,9 @@
 levels (DESIGN.md §13.5).
 
 Dense serve levels have two kernel formulations: the packed layout's
-selective-OR pull (``kernels/pull_ms_packed.py``, one grid pass walking
-every VSS block, its marks ORed into their rows by the slot-table
-gather of ``kernels/gather_or.py``) and the blocked bit-matrix product
+selective-OR pull (the slot-table gather of ``kernels/gather_or.py``,
+which gathers each slot's parent frontier tile, computes its marks in
+place and ORs them into their rows) and the blocked bit-matrix product
 (``kernels/pull_mma_ms_packed.py``), which unpacks the VSS bit-tiles to
 int8 planes once at tile prep and turns each dense sweep into MXU-shaped
 ``(block, tau, sigma) x (block, sigma, kappa)`` batched matmuls.  On CPU

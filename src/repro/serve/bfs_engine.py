@@ -27,10 +27,10 @@ Lane substrates
 Three bit-for-bit equivalent lane layouts implement the level step:
 
 * ``layout='packed'`` — the paper-faithful kappa-bit packed words
-  (``(n_ext, kappa/32)`` uint32).  Dense levels run the
-  ``kernels/pull_ms_packed.py`` Pallas pull and OR its marks into their
-  rows with a gather over a static slot table (``kernels/gather_or.py``,
-  DESIGN.md §11.2); queued levels run
+  (``(n_ext, kappa/32)`` uint32).  Dense levels gather each slot's
+  parent frontier tile over a static slot table, compute its marks in
+  place and OR them into their rows (``kernels/gather_or.py``, DESIGN.md
+  §11.2); queued levels run
   ``kernels/pull_ms_packed_queued.py`` + ``kernels/scatter_or.py`` (or
   their jnp references when ``use_pallas=False``).  1/8 the state
   traffic; the TPU path.
@@ -144,7 +144,7 @@ Megatick traversal (DESIGN.md §11)
 ----------------------------------
 ``BfsEngine(megatick=T)`` with ``T > 1`` moves the per-graph level loop
 on-device: up to ``T`` consecutive dense levels run inside one
-``jax.lax.while_loop`` dispatch (pull + slot-table gather-OR on the
+``jax.lax.while_loop`` dispatch (the slot-table gather-OR pull on the
 packed substrate, diff, level
 stamps, per-lane reach, the Eq. (6) decision, and per-lane done flags all
 stay resident), returning to host only when every active lane has
@@ -184,8 +184,6 @@ from repro.core.msbfs_packed import frontier_planes, unpack_levels_check
 from repro.kernels import ops
 from repro.kernels import pull_mma_ms_packed as mma_mod
 from repro.kernels.gather_or import gather_or, slot_table
-from repro.kernels.pull_ms_packed import (
-    lanes_of, pull_ms_packed_lanes, pull_ms_packed_ref)
 from repro.kernels.pull_ms_packed_queued import (
     pull_ms_packed_queued, pull_ms_packed_queued_ref)
 from repro.kernels.scatter_or import scatter_or, scatter_or_ref
@@ -1082,7 +1080,8 @@ class _GraphOperands(NamedTuple):
     nz_parent: jax.Array | None
     nz_rows: jax.Array | None
     # the packed dense level's destination-major slot table (§11.2)
-    chunks: jax.Array | None
+    slot_parents: jax.Array | None
+    slot_masks: jax.Array | None
     chunk_rows: jax.Array | None
 
 
@@ -1166,15 +1165,17 @@ class _LaneRunner:
             nz = (self.put(mask_c), self.put(parent_c.astype(np.int32)),
                   self.put(rows_c.astype(np.int32)))
             self._pad_slice = int(mask_c.size - 1)  # the sentinel entry
-        # the packed dense level ORs marks into rows through a gather over
-        # a static slot table (§11.2); entries gathered a level, for stats
+        # the packed dense level pulls and ORs into rows through a gather
+        # over a static slot table (§11.2); entries gathered a level, for
+        # stats
         self.table, self.dense_gathered = None, 0
-        dev_table = (None, None)
+        dev_table = (None, None, None)
         if self.substrate == "packed" and not self._mma:
             self.table = table if table is not None else slot_table(
-                np.asarray(bd.row_ids), np.asarray(bd.masks), bd.n_ext,
-                self.kw)
-            dev_table = (self.put(self.table.chunks),
+                np.asarray(bd.row_ids), np.asarray(bd.masks),
+                np.asarray(bd.v2r), bd.n_ext)
+            dev_table = (self.put(self.table.parents),
+                         self.put(self.table.masks),
                          self.put(self.table.rows))
             self.dense_gathered = self.table.entries
         self._ops = _GraphOperands(bd, self._tiles, *nz, *dev_table)
@@ -1277,15 +1278,10 @@ class _LaneRunner:
                     interpret=self._interpret)
             return mma_mod.pull_scatter_mma_ms_packed_ref(
                 v, t.a_planes, f, t.v2r, t.rows)
-        # the dense pull's lane-dense marks, ORed into their rows by the
-        # destination-major gather over the static slot table (§11.2)
-        if self.use_pallas:
-            marks = pull_ms_packed_lanes(bd.masks, f, bd.v2r, sigma=bd.sigma,
-                                         interpret=self._interpret)
-        else:
-            marks = lanes_of(pull_ms_packed_ref(bd.masks, f[bd.v2r],
-                                                sigma=bd.sigma))
-        return v | gather_or(marks, g.chunks, g.chunk_rows, self.kw)
+        # every slot's parent frontier tile, its marks computed in place
+        # and ORed into their rows by the destination-major gather over
+        # the static slot table (§11.2)
+        return v | gather_or(f, g.slot_parents, g.slot_masks, g.chunk_rows)
 
     def _pull_scatter_queued(self, g: "_GraphOperands", v, f, qids):
         """Frontier-compacted pull+scatter over the active list only

@@ -68,10 +68,18 @@ SERVE = textwrap.dedent("""
     art = eng.cache.peek("g")
     out["placement"] = [
         all(a.devices() == {devs[k]} and a.committed
-            for a in (r._ops.chunks, r._ops.chunk_rows, *r.init_state(),
+            for a in (r._ops.slot_parents, r._ops.slot_masks,
+                      r._ops.chunk_rows, *r.init_state(),
                       *group.replicas[k].state))
         for k, r in enumerate(runners)]
-    out["one_host_table"] = all(r.table is runners[0].table for r in runners)
+    # every chip's stage-1 parents and masks and stage-2 rows are copies
+    # of the one host table the replicas share
+    out["one_host_table"] = all(
+        r.table is runners[0].table
+        and np.array_equal(np.asarray(r._ops.slot_parents), r.table.parents)
+        and np.array_equal(np.asarray(r._ops.slot_masks), r.table.masks)
+        and np.array_equal(np.asarray(r._ops.chunk_rows), r.table.rows)
+        for r in runners)
     out["replica0_is_bd"] = art.replicas[0] is art.bd
     out["replica_bd_devices"] = [
         bd.row_ids.devices() == {devs[k]} and bd.masks.devices() == {devs[k]}

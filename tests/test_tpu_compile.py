@@ -1,4 +1,5 @@
-"""Ahead-of-time compiles of the serve-path Pallas kernels for a described
+"""Ahead-of-time compiles of the serve-path Pallas kernels, and of the
+packed dense level's gather-OR (plain XLA, no kernel), for a described
 TPU v5e, at the shapes ``chip_smoke.py`` serves (urand scale 20: about
 16.8M BVSS slots, 1M visited rows, kappa = 32, so one packed word per
 row).
@@ -13,6 +14,7 @@ from __future__ import annotations
 
 import os
 import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -21,8 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
 from repro.kernels import pull_mma_ms_packed as mma
-from repro.kernels.gather_or import gather_or
-from repro.kernels.pull_ms_packed import pull_ms_packed_lanes
+from repro.kernels.gather_or import _block_cols, gather_or
 from repro.kernels.pull_ms_packed_queued import pull_ms_packed_queued
 from repro.kernels.pull_scatter_ms_packed import pull_scatter_ms_packed
 from repro.kernels.scatter_or import scatter_or
@@ -32,12 +33,20 @@ TAU, SIGMA, KW = 128, 8, 1
 N_EXT = (1 << 20) + 8   # visited rows: n_pad + sigma
 N_SETS = (1 << 17) + 1  # slice sets + the sentinel set
 BUCKET = 4096           # a queued-level bucket of active VSSs
-CHUNKS, D, C = 2_300_000, 8, 8   # the dense level's slot table (§11.2)
+# the dense level's slot table (§11.2): D = 32 as the rule gives urand,
+# about 1.5 chunks a row, in whole stage-1 blocks
+D, C = 32, 4
+COLS = -(-790_000 // _block_cols(D, 790_000)) * _block_cols(D, 790_000)
+# the dense level compiles in about 2 s; a gather the TPU compiler
+# handles badly (a short minor index axis) took over 70 s
+DENSE_COMPILE_S = 60.0
+# what the dense level may hold in HBM beside its operands: the gathered
+# tiles stay on chip (lane-padded in HBM they would be 16x the table)
+DENSE_TEMP_BYTES = 256 << 20
 # the name each serve-path kernel carries in the compiled program, which a
 # device trace names its op by (the queued pull is ``pull_ms_packed``'s
 # kernel, run on the gathered queued VSSs)
 KERNEL_NAMES = {"pull_scatter_ms_packed": "pull_scatter_ms_packed",
-                "dense_gather_or": "pull_ms_packed",
                 "pull_ms_packed_queued": "pull_ms_packed",
                 "scatter_or": "scatter_or"}
 
@@ -88,12 +97,12 @@ def _cases(spec):
             lambda *a: pull_scatter_ms_packed(*a, sigma=SIGMA,
                                               interpret=False),
             (v, masks, f, v2r, rows)),
-        # the packed dense level: the pull, then the slot-table gather-OR
+        # the packed dense level: the slot-table gather-OR of parent
+        # frontier tiles, pull and scatter in one (no kernel)
         "dense_gather_or": (
-            lambda v, m, f, v2r, chunks, rows: v | gather_or(
-                pull_ms_packed_lanes(m, f, v2r, sigma=SIGMA,
-                                     interpret=False), chunks, rows, KW),
-            (v, masks, f, v2r, spec((D, CHUNKS), jnp.int32),
+            lambda v, f, parents, slot_masks, rows: v | gather_or(
+                f, parents, slot_masks, rows),
+            (v, f, spec((D, COLS), jnp.int32), spec((D, COLS), jnp.uint8),
              spec((C, N_EXT), jnp.int32))),
         "pull_ms_packed_queued": (
             lambda *a: pull_ms_packed_queued(*a, sigma=SIGMA,
@@ -130,7 +139,18 @@ def _cases(spec):
     "frontier_sweep"])
 def test_kernel_compiles_for_v5e(spec, kernel):
     fn, args = _cases(spec)[kernel]
-    text = jax.jit(fn).lower(*args).compile().as_text()
+    t = time.perf_counter()
+    compiled = jax.jit(fn).lower(*args).compile()
+    compile_s = time.perf_counter() - t
+    text = compiled.as_text()
+    if kernel == "dense_gather_or":
+        # plain XLA: no Mosaic kernel, a prompt compile, and no gathered
+        # tiles written out to HBM
+        assert "tpu_custom_call" not in text
+        assert compile_s < DENSE_COMPILE_S, compile_s
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < DENSE_TEMP_BYTES, temp
+        return
     # the Mosaic kernel is in the program (not the interpreter's XLA ops)
     assert "tpu_custom_call" in text
     if kernel in KERNEL_NAMES:
